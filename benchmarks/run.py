@@ -71,6 +71,7 @@ def main() -> None:
          live.run),
     ]
     print("name,us_per_call,derived")
+    failed = []
     for key, desc, fn in suites:
         if want and key not in want:
             continue
@@ -80,6 +81,9 @@ def main() -> None:
         except Exception:
             traceback.print_exc()
             print(f"{key}/ERROR,0.0,failed")
+            failed.append(key)
+    if failed:
+        sys.exit(f"failed suites: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -3,8 +3,11 @@
 The container has one physical CPU core, so wall-clock multi-device speedup
 is not measurable; what IS measurable — and what this sweep asserts — is the
 paper's scaling *invariants* on emulated host devices
-(``XLA_FLAGS=--xla_force_host_platform_device_count=8``, in a subprocess so
-the parent's device state is untouched):
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8``). The sweep runs in
+a child process that is CPU-only: its own environment sets
+``JAX_PLATFORMS=cpu``, so it never asks for a chip the parent may hold. The
+same invariants on four real chips are checked by
+``python chip_smoke.py --four-chips``.
 
 * **correctness** — pipelined sharded training (mesh ``data=N``, fsdp
   profile) reproduces the single-device sync per-step losses within float
@@ -19,8 +22,8 @@ the parent's device state is untouched):
 The summary (per-device param/entity bytes, steps/s, retrace counts) lands
 in ``BENCH_scaling.json`` at the repo root so the perf trajectory
 accumulates across PRs; violated invariants raise, so CI fails loudly when
-invoked directly (``benchmarks/run.py`` converts the raise into an ERROR
-CSV row per its contract).
+invoked directly (``benchmarks/run.py`` prints an ERROR CSV row for it and
+exits non-zero).
 """
 from __future__ import annotations
 
@@ -133,7 +136,8 @@ def run(out_path: str = _DEFAULT_OUT) -> dict:
               .replace("__DEVICE_COUNTS__", repr(tuple(DEVICE_COUNTS)))
               .replace("__MAX_DEVICES__", str(max(DEVICE_COUNTS))))
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, timeout=1800, cwd=_REPO_ROOT)
+                       text=True, timeout=1800, cwd=_REPO_ROOT,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     lines = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")]
     try:
         data = json.loads(lines[0][len("RESULT "):]) if lines else None

@@ -20,13 +20,6 @@ if __package__ in (None, ""):  # direct `python benchmarks/throughput.py`
     _root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
     sys.path.insert(0, os.path.join(_root, "src"))
     sys.path.insert(0, _root)
-    # Pin XLA-CPU to one intra-op thread: applies equally to both engines,
-    # leaves a core for the host pipeline, and cuts run-to-run variance on
-    # small shared machines. Must be set before jax initializes.
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_cpu_multi_thread_eigen=false intra_op_parallelism_threads=1"
-    ).strip()
 
 import numpy as np
 

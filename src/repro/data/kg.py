@@ -1,11 +1,11 @@
 """Knowledge-graph storage: triple store + CSR adjacency + synthetic generators.
 
-The container is offline, so the paper's six benchmark KGs (Table 4) are
-represented two ways:
-  * ``full``   — exact Table 4 statistics, used ONLY by the dry-run
-                 (ShapeDtypeStruct; never materialized).
-  * ``reduced``— small synthetic graphs with the same family (power-law
-                 degrees, same relation/entity ratio) for CPU tests and
+Nothing is downloaded, so the paper's six benchmark KGs (Table 4) are
+synthetic stand-ins of the same family (power-law degrees, skewed relation
+usage), generated from a seed at one of two scales:
+  * ``full``   — the exact Table 4 entity, relation and train/valid/test
+                 triple counts (``load_dataset(name, reduced=False)``);
+  * ``reduced``— small graphs (``REDUCED_SCALE``) for CPU tests and
                  benchmarks.
 
 Live-write layer (DESIGN.md §LiveStore): the store is append-only but no
@@ -359,12 +359,29 @@ class KnowledgeGraph(_AdjacencyReader):
         return np.unique(self.triples[:, 2])
 
 
+# Zipf exponent of head/tail entity popularity in the synthetic graphs.
+HUB_EXPONENT = 0.8
+
+
+def _draw_triples(rng, n_entities: int, n_relations: int, m: int,
+                  hub_exponent: float) -> np.ndarray:
+    """m (h, r, t) rows: Zipf-like heads/tails, skewed relations."""
+    ent_w = (np.arange(1, n_entities + 1, dtype=np.float64)) ** (-hub_exponent)
+    ent_p = ent_w / ent_w.sum()
+    rel_w = (np.arange(1, n_relations + 1, dtype=np.float64)) ** (-0.5)
+    rel_p = rel_w / rel_w.sum()
+    h = rng.choice(n_entities, size=m, p=ent_p)
+    t = rng.choice(n_entities, size=m, p=ent_p)
+    r = rng.choice(n_relations, size=m, p=rel_p)
+    return np.stack([h, r, t], axis=1)
+
+
 def generate_synthetic_kg(
     n_entities: int,
     n_relations: int,
     n_triples: int,
     seed: int = 0,
-    hub_exponent: float = 0.8,
+    hub_exponent: float = HUB_EXPONENT,
     name: str = "synthetic",
 ) -> KnowledgeGraph:
     """Power-law synthetic KG (degree-weighted, matching App. C's sampling).
@@ -373,16 +390,9 @@ def generate_synthetic_kg(
     has hub structure like FB15k/wikikg2; relation usage is also skewed.
     """
     rng = np.random.default_rng(seed)
-    ent_w = (np.arange(1, n_entities + 1, dtype=np.float64)) ** (-hub_exponent)
-    ent_p = ent_w / ent_w.sum()
-    rel_w = (np.arange(1, n_relations + 1, dtype=np.float64)) ** (-0.5)
-    rel_p = rel_w / rel_w.sum()
     # Oversample then dedup to hit ~n_triples unique triples.
-    m = int(n_triples * 1.3) + 16
-    h = rng.choice(n_entities, size=m, p=ent_p)
-    t = rng.choice(n_entities, size=m, p=ent_p)
-    r = rng.choice(n_relations, size=m, p=rel_p)
-    tri = np.stack([h, r, t], axis=1)
+    tri = _draw_triples(rng, n_entities, n_relations,
+                        int(n_triples * 1.3) + 16, hub_exponent)
     kg = KnowledgeGraph(n_entities, n_relations, tri, name=name)
     if len(kg) > n_triples:
         keep = rng.choice(len(kg), size=n_triples, replace=False)
@@ -417,14 +427,34 @@ REDUCED_SCALE: Dict[str, Tuple[int, int, int]] = {
 }
 
 
+def generate_table4_kg(stats: KGStats, seed: int = 0):
+    """Synthetic graph at the exact Table 4 counts of ``stats``: draws until
+    ``n_total`` distinct triples exist, then splits them into exactly
+    ``n_train`` / ``n_valid`` / ``n_test``. Returns (train_kg, full_kg)."""
+    rng = np.random.default_rng(seed)
+    E, R, n = stats.n_entities, stats.n_relations, stats.n_total
+    tri = np.unique(_draw_triples(rng, E, R, int(n * 1.3) + 16, HUB_EXPONENT),
+                    axis=0)
+    while len(tri) < n:
+        more = _draw_triples(rng, E, R, 2 * (n - len(tri)) + 1024,
+                             HUB_EXPONENT)
+        tri = np.unique(np.concatenate([tri, more]), axis=0)
+    tri = tri[rng.permutation(len(tri))[:n]]
+    full = KnowledgeGraph(E, R, tri, name=stats.name)
+    train = KnowledgeGraph(E, R, tri[:stats.n_train],
+                           name=stats.name + "-train")
+    return train, full
+
+
 def load_dataset(name: str, reduced: bool = True, seed: int = 0):
-    """Returns (train_kg, full_kg, stats). ``reduced`` is mandatory on CPU;
-    full-scale graphs exist only as ShapeDtypeStructs in the dry-run."""
+    """Returns (train_kg, full_kg, stats). ``reduced`` selects the small
+    CPU stand-in; ``reduced=False`` builds the graph at the exact Table 4
+    statistics (valid + test triples are the ones in ``full_kg`` but not in
+    ``train_kg``)."""
     stats = TABLE4[name]
     if not reduced:
-        raise RuntimeError(
-            "Full-scale KGs are dry-run-only in this container; use reduced=True."
-        )
+        train_kg, full = generate_table4_kg(stats, seed=seed)
+        return train_kg, full, stats
     e, r, t = REDUCED_SCALE[name]
     full = generate_synthetic_kg(e, r, t, seed=seed, name=name)
     train_kg, _, _ = split_kg(full, seed=seed)

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.lm.moe import shard_map  # version-bridging wrapper
-
 
 def gpipe_forward(stage_fn: Callable, stage_params, x_microbatches,
                   mesh, axis: str = "pod"):
@@ -67,10 +65,11 @@ def gpipe_forward(stage_fn: Callable, stage_params, x_microbatches,
 
     P = jax.sharding.PartitionSpec
     stage_spec = jax.tree.map(lambda _: P(axis), stage_params)
-    stacked = shard_map(
-        local, mesh,
+    stacked = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(stage_spec, P()),
         out_specs=P(axis),
+        check_vma=False,
     )(stage_params, x_microbatches)
     return stacked[-1]  # the last stage holds the real outputs
 

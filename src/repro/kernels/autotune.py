@@ -158,7 +158,9 @@ def intersect_candidates(bucket) -> List[Dict[str, int]]:
 def gather_fuse_candidates(bucket) -> List[Dict[str, int]]:
     nb = bucket[0]
     out = [dict(DEFAULTS["gather_fuse"])]
-    for rows in (2, 4, 8, 16, 32, 64):
+    # Blocked tiles are whole f32 sublane groups: Mosaic refuses a (rows, d)
+    # block whose rows is not a multiple of 8.
+    for rows in (8, 16, 32, 64):
         if rows <= nb:
             c = {"rows": rows}
             if c not in out:

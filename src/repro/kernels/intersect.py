@@ -8,7 +8,9 @@ round-trips, which is exactly where the paper's 13.1× per-operator win comes
 from (fragmented per-query launches → dense class-wide fusion).
 
 k is a *static* kernel parameter (one compiled kernel per equivalence class,
-mirroring Eq. 8); d and the MLP hidden dim are padded to 128 lanes by ops.py.
+mirroring Eq. 8). The wrapper hands the kernel a k-major [k, n, d] view so
+each grid step sees k separate [bn, d] tiles; the softmax and the combine
+over k are unrolled in the kernel body.
 """
 from __future__ import annotations
 
@@ -19,31 +21,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _intersect_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref, *, k: int):
-    x = x_ref[...].astype(jnp.float32)                      # [bn, k, d]
+    # x_ref is k-major [k, bn, d]: each input of the class is one 2-D
+    # [bn, d] tile, so every op below is a plain 2-D matmul or an
+    # elementwise op on [bn, ·] — forms Mosaic lowers (a batched
+    # "nk,nkd->nd" contraction or a [bn, k, d] -> [bn*k, d] reshape is not).
     w1 = w1_ref[...].astype(jnp.float32)                    # [d, hd]
     b1 = b1_ref[...].astype(jnp.float32)                    # [1, hd]
-    w2 = w2_ref[...].astype(jnp.float32)                    # [hd, 1... padded 128]
+    w2 = w2_ref[...].astype(jnp.float32)                    # [hd, pad] (col 0 real)
     b2 = b2_ref[...].astype(jnp.float32)                    # [1, pad]
-    bn, kk, d = x.shape
-    h = jnp.maximum(
-        jax.lax.dot_general(
-            x.reshape(bn * kk, d), w1, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        + b1,
-        0.0,
-    )                                                        # [bn*k, hd]
-    logits = (
-        jax.lax.dot_general(h, w2, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        + b2
-    )[:, :1].reshape(bn, kk)                                 # [bn, k]
-    att = jax.nn.softmax(logits, axis=1)
-    o_ref[...] = jnp.einsum("nk,nkd->nd", att, x).astype(o_ref.dtype)
+    xs, logits = [], []
+    for j in range(k):
+        xj = x_ref[j].astype(jnp.float32)                   # [bn, d]
+        h = jnp.maximum(
+            jax.lax.dot_general(xj, w1, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32) + b1,
+            0.0)                                             # [bn, hd]
+        lj = (jax.lax.dot_general(h, w2, (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+              + b2)[:, :1]                                   # [bn, 1]
+        xs.append(xj)
+        logits.append(lj)
+    # Softmax over the k inputs, unrolled: k is a small static class size.
+    m = functools.reduce(jnp.maximum, logits)
+    ex = [jnp.exp(lj - m) for lj in logits]
+    denom = functools.reduce(jnp.add, ex)
+    out = functools.reduce(jnp.add, [(e / denom) * xj for e, xj in zip(ex, xs)])
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -75,11 +80,12 @@ def intersect_pallas(
             f"intersect: logit head input dim {w2.shape[0]} != hidden dim "
             f"hd={hd}")
     grid = (n // bn,)
+    xt = jnp.swapaxes(x, 0, 1)                               # [k, n, d]
     return pl.pallas_call(
         functools.partial(_intersect_kernel, k=k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bn, k, d), lambda i: (i, 0, 0)),
+            pl.BlockSpec((k, bn, d), lambda i: (0, i, 0)),
             pl.BlockSpec((d, hd), lambda i: (0, 0)),
             pl.BlockSpec((1, hd), lambda i: (0, 0)),
             pl.BlockSpec((hd, pad), lambda i: (0, 0)),
@@ -88,5 +94,5 @@ def intersect_pallas(
         out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
-    )(x, w1, b1.reshape(1, hd), w2, b2.reshape(1, pad))
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+    )(xt, w1, b1.reshape(1, hd), w2, b2.reshape(1, pad))

@@ -1,9 +1,12 @@
 """Jitted public wrappers for the Pallas kernels: shape padding to hardware
-tiles, dtype handling, and interpret-mode fallback on CPU hosts.
+tiles and dtype handling.
 
-On a CPU host (this container) the kernels run with interpret=True, which
-executes the kernel body in Python — bit-accurate semantics, no TPU needed.
-On TPU the same call sites compile to Mosaic.
+``interpret=None`` (the default) picks interpret mode when JAX's default
+backend is not a TPU — the CPU test setting, which runs the kernel body
+through the Pallas interpreter and checks its arithmetic but not whether
+Mosaic accepts it. Code that must run the compiled kernel passes
+``interpret=False`` (``chip_smoke.py`` does), so a run that lands on the
+wrong device fails instead of interpreting.
 
 Tile configs resolve through the process autotuner (DESIGN.md §Autotuner):
 pass ``bm``/``bn``/``rows`` explicitly to pin a config (the tuner's sweep

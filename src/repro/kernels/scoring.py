@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _scoring_kernel(q_ref, e_ref, o_ref, acc_ref, *, nk: int, gamma: float, mode: str):
     k = pl.program_id(2)
@@ -89,7 +87,7 @@ def scoring_pallas(
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(q, e)

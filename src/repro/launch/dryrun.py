@@ -42,6 +42,9 @@ from repro.lm.shapes import SHAPES, cell_supported, input_specs
 from repro.lm.steps import make_decode_step, make_prefill_step, make_train_step
 from repro.training.optim import adam_init
 
+# The chip the production mesh is made of (roofline peaks are keyed by it).
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def _mem_analysis(compiled) -> Dict:
     try:
@@ -194,16 +197,17 @@ def run_cell(arch: str, shape: str, multi_pod: bool = False,
             exact = _exact_cost(cfg, shape, mesh, n_dev, profile)
             rec["cost_exact"] = exact
             rec["roofline"] = roofline_terms(
-                exact["flops"], exact["bytes_accessed"], exact["wire_bytes"])
+                exact["flops"], exact["bytes_accessed"], exact["wire_bytes"],
+                TARGET_DEVICE_KIND)
         except Exception:
             rec["cost_exact"] = {"error": traceback.format_exc(limit=10)}
             rec["roofline"] = roofline_terms(
                 cost.get("flops", 0.0), cost.get("bytes accessed", 0.0),
-                coll.wire_bytes)
+                coll.wire_bytes, TARGET_DEVICE_KIND)
     else:
         rec["roofline"] = roofline_terms(
             cost.get("flops", 0.0), cost.get("bytes accessed", 0.0),
-            coll.wire_bytes)
+            coll.wire_bytes, TARGET_DEVICE_KIND)
 
     mf = model_flops(cfg, cell, cell.kind)
     rec["model_flops_global"] = mf
@@ -350,7 +354,7 @@ def run_ngdb_cell(multi_pod: bool = False, dataset: str = "ogbl-wikikg2",
     rec["collectives"] = coll.as_dict()
     rec["roofline"] = roofline_terms(cost.get("flops", 0.0),
                                      cost.get("bytes accessed", 0.0),
-                                     coll.wire_bytes)
+                                     coll.wire_bytes, TARGET_DEVICE_KIND)
     rec["schedule_stats"] = prepared.sched.stats
     return rec
 

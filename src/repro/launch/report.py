@@ -11,7 +11,6 @@ import os
 import sys
 from typing import Dict, List
 
-from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
 
 ARCH_ORDER = [
     "jamba-v0.1-52b", "qwen2-72b", "qwen3-4b", "qwen2-0.5b", "internlm2-20b",

@@ -17,10 +17,32 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-# TPU v5e hardware constants (per chip), per the assignment.
-PEAK_FLOPS = 197e12        # bf16
-HBM_BW = 819e9             # bytes/s
-ICI_BW = 50e9              # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float    # bf16 FLOP/s
+    hbm_bw: float   # HBM bytes/s
+    ici_bw: float   # interchip bytes/s per link
+
+
+# Published per-chip peaks, keyed by jax ``Device.device_kind``.
+# "TPU v5 lite": Google Cloud TPU documentation, "TPU v5e" — 197 TFLOP/s
+# bf16, 819 GB/s HBM, 1,600 Gbit/s interchip interconnect per chip (four
+# links of 50 GB/s).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; a kind without published peaks is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to roofline.PEAKS with their source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -109,10 +131,12 @@ def parse_collectives(hlo_text: str, total_devices: int) -> CollectiveStats:
     return CollectiveStats(wire, payload, by_type, counts)
 
 
-def roofline_terms(flops: float, bytes_accessed: float, wire_bytes: float) -> Dict:
-    compute_t = flops / PEAK_FLOPS
-    memory_t = bytes_accessed / HBM_BW
-    coll_t = wire_bytes / ICI_BW
+def roofline_terms(flops: float, bytes_accessed: float, wire_bytes: float,
+                   device_kind: str) -> Dict:
+    pk = peaks_for(device_kind)
+    compute_t = flops / pk.flops
+    memory_t = bytes_accessed / pk.hbm_bw
+    coll_t = wire_bytes / pk.ici_bw
     terms = {"compute_s": compute_t, "memory_s": memory_t, "collective_s": coll_t}
     dom = max(terms, key=terms.get)
     bound = max(terms.values())

@@ -28,6 +28,7 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
@@ -41,6 +42,7 @@ from repro.serving import (ServingConfig, ServingEngine, make_workload,
                            run_closed_loop, run_open_loop, scorer_for,
                            topk_desc)
 from repro.training.checkpoint import load_checkpoint
+from repro.xla_cache import enable_persistent_cache
 
 __all__ = ["serve_batch", "topk_desc", "main"]  # topk_desc re-exported
 
@@ -175,9 +177,17 @@ def _serve_tier(args, kg, model, params, ctx) -> None:
     router.close()
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> Optional[Dict]:
+    """Serve a generated workload; returns the load report, the workload
+    and the served params (None for the multi-replica tier)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="FB15k")
+    ap.add_argument("--full-scale", action="store_true",
+                    help="build the graph at the exact Table 4 statistics "
+                         "instead of the small reduced stand-in")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the synthetic graph (match the training "
+                         "run's --seed)")
     ap.add_argument("--model", default="betae", choices=model_names())
     ap.add_argument("--dim", type=int, default=64)
     ap.add_argument("--ckpt-dir", default=None)
@@ -263,14 +273,16 @@ def main() -> None:
                          "§Autotuner): tuned configs load from PATH and the "
                          "serving executor pads pools kernel-aware; also the "
                          "default via REPRO_AUTOTUNE_CACHE (run.sh sets it)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    print(f"persistent compile cache: {enable_persistent_cache()}")
 
     ctx = make_execution_context(args.mesh, profile=args.profile)
     if ctx.is_sharded:
         print(f"execution context: {ctx.describe()} "
               f"({ctx.n_devices} devices, dp={ctx.dp_size})")
 
-    kg, _, _ = load_dataset(args.dataset)
+    kg, _, _ = load_dataset(args.dataset, reduced=not args.full_scale,
+                            seed=args.seed)
     store, cache = None, None
     sem_dim = 0
     if args.semantic_store:
@@ -288,12 +300,13 @@ def main() -> None:
                                    entity_pad=max(1, ctx.n_devices)))
     params = model.init_params(jax.random.PRNGKey(0), kg.n_entities,
                                kg.n_relations, semantic_cache=cache, ctx=ctx)
+    restored_step = None
     if args.ckpt_dir:
         restored = load_checkpoint(args.ckpt_dir,
                                    template={"params": params, "opt": None})
         if restored:
-            params = restored[1]["params"]
-            print(f"loaded checkpoint step={restored[0]}")
+            restored_step, params = restored[0], restored[1]["params"]
+            print(f"loaded checkpoint step={restored_step}")
             if cache is not None:
                 cache.reset()  # restored cache buffers: nothing resident yet
 
@@ -440,6 +453,8 @@ def main() -> None:
                         "metrics": get_registry().snapshot()})
         print(f"metrics: wrote {args.metrics}")
     engine.close()
+    return {"report": report, "workload": workload, "model": model,
+            "params": params, "restored_step": restored_step, "stats": st}
 
 
 if __name__ == "__main__":

@@ -14,12 +14,21 @@ only a bounded device-resident hot set:
   PYTHONPATH=src python -m repro.launch.train --dataset FB15k --model gqe \
       --semantic --semantic-store /tmp/sem --semantic-budget-rows 2048 \
       --semantic-quant fp32 --pipeline --steps 200
+
+At the published size (Table 4 graph, dim 400, the NGDB batch):
+
+  PYTHONPATH=src python -m repro.launch.train --dataset FB15k-237 \
+      --full-scale --model gqe --dim 400 --batch-size 512 --negatives 64
+
+``main(argv)`` returns the trainer and the eval metrics, so one process can
+drive training and check what it produced (``chip_smoke.py`` does).
 """
 from __future__ import annotations
 
 import argparse
 import json
 import time
+from typing import Dict, Optional, Sequence
 
 import numpy as np
 
@@ -33,6 +42,7 @@ from repro.semantic import (PTEConfig, SemanticCache, SemanticStore,
                             precompute_semantic_table,
                             precompute_semantic_table_to_store)
 from repro.training import AdamConfig, NGDBTrainer, TrainConfig, evaluate
+from repro.xla_cache import enable_persistent_cache
 
 
 def open_or_build_store(directory: str, kg, d_l: int, quant: str,
@@ -59,9 +69,15 @@ def open_or_build_store(directory: str, kg, d_l: int, quant: str,
     return store
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> Dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="FB15k")
+    ap.add_argument("--full-scale", action="store_true",
+                    help="build the graph at the exact Table 4 statistics "
+                         "instead of the small reduced stand-in")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the synthetic graph, the params and the "
+                         "query sampler")
     ap.add_argument("--model", default="betae", choices=model_names())
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch-size", type=int, default=128)
@@ -144,7 +160,8 @@ def main() -> None:
                          "regime before training (results persist to "
                          "--autotune-cache when given); without this flag "
                          "only already-tuned configs are used")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    print(f"persistent compile cache: {enable_persistent_cache()}")
     if args.semantic_store:
         args.semantic = True
     if args.trace:
@@ -156,8 +173,11 @@ def main() -> None:
         print(f"execution context: {ctx.describe()} "
               f"({ctx.n_devices} devices, dp={ctx.dp_size})")
 
-    kg, full_kg, stats = load_dataset(args.dataset)
-    print(f"dataset={args.dataset} (reduced stand-in): "
+    kg, full_kg, stats = load_dataset(args.dataset,
+                                      reduced=not args.full_scale,
+                                      seed=args.seed)
+    scale = "Table 4 scale" if args.full_scale else "reduced stand-in"
+    print(f"dataset={args.dataset} ({scale}): "
           f"{kg.n_entities} entities, {kg.n_relations} relations, {len(kg)} train triples")
 
     table, store, cache = None, None, None
@@ -211,7 +231,7 @@ def main() -> None:
         executor=args.executor, checkpoint_dir=args.ckpt_dir,
         pipeline=args.pipeline, max_inflight=args.max_inflight,
         cse=not args.no_cse, materialized_rows=args.materialized_rows,
-        metrics_path=args.metrics,
+        metrics_path=args.metrics, seed=args.seed,
     )
     trainer = NGDBTrainer(model, kg, cfg, semantic_table=table,
                           semantic_cache=cache, ctx=ctx)
@@ -307,13 +327,14 @@ def main() -> None:
             stage = cache.plan(anchors)
         except RuntimeError as e:
             print(f"eval skipped: {e}")
-            return
+            return {"trainer": trainer, "eval": None, "mode": mode}
         if stage is not None:
             trainer.params = cache.apply_to(trainer.params, stage)
         score_all_fn = lambda p, q: model.score_all_chunked(p, q, store.read_rows)  # noqa: E731
     metrics = evaluate(model, trainer.params, trainer.executor, full_kg,
                        eval_qs, train_kg=kg, score_all_fn=score_all_fn)
     print("eval:", json.dumps({k: round(float(v), 4) for k, v in metrics.items()}))
+    return {"trainer": trainer, "eval": metrics, "mode": mode}
 
 
 if __name__ == "__main__":

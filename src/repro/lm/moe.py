@@ -26,18 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-    """Version-bridging shard_map wrapper (jax.shard_map in >= 0.8)."""
-    import jax as _jax
-
-    if hasattr(_jax, "shard_map"):
-        return _jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_rep)
-    from jax.experimental.shard_map import shard_map as _sm  # pragma: no cover
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep)
-
 
 def pack_by_expert(x, expert_idx, gates, n_experts: int, capacity: int):
     """Sort-based pool packing. x [T, D]; expert_idx/gates [T, k].
@@ -149,11 +137,11 @@ def moe_ffn(x, router, w_gate, w_up, w_down, cfg, mesh=None,
         w_specs = (P("model", None, None), P("model", None, None), P("model", None, None))
     else:
         w_specs = (P(None, None, "model"), P(None, None, "model"), P(None, "model", None))
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_moe_local, model_axis="model", ep_shards=m, **kw),
         mesh=mesh,
         in_specs=(P(dp, None), P(None, None)) + w_specs,
         out_specs=P(dp, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x2, router, w_gate, w_up, w_down).reshape(shape)

@@ -83,7 +83,6 @@ def test_cache_sharding_specs_decode():
 
 
 def test_compressed_psum_single_axis():
-    from repro.lm.moe import shard_map
     from repro.training.compression import compressed_psum
 
     mesh = jax.make_mesh((1,), ("x",))
@@ -93,8 +92,8 @@ def test_compressed_psum_single_axis():
     def f(g, e):
         return compressed_psum(g, "x", e)
 
-    out, new_err = shard_map(
-        f, mesh, in_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),
+    out, new_err = jax.shard_map(
+        f, mesh=mesh, check_vma=False, in_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),
         out_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),
     )(g, err)
     # single peer: mean == dequantized value; error feedback = quant residual
@@ -205,6 +204,7 @@ def test_elastic_restore_subprocess():
     script = r"""
 import os, tempfile
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"  # emulated host devices only
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -235,6 +235,7 @@ def test_gpipe_matches_sequential_subprocess():
     script = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ["JAX_PLATFORMS"] = "cpu"  # emulated host devices only
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline_parallel import gpipe_forward
@@ -274,6 +275,7 @@ def test_cse_encode_parity_under_mesh_subprocess():
     script = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"  # emulated host devices only
 import sys; sys.path.insert(0, "src")
 import jax, numpy as np
 from repro.core import PooledExecutor
@@ -313,6 +315,7 @@ def test_spmd_16dev_subprocess():
     script = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
+os.environ["JAX_PLATFORMS"] = "cpu"  # emulated host devices only
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
 from repro.lm.config import LMConfig

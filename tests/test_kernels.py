@@ -51,6 +51,31 @@ def test_gather_fuse_sweep(E, d, dl, dp, n, rng):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_gather_fuse_edge_tile(dtype, rng):
+    """rows=1 DMAs the aligned sublane tile holding each row (8 f32 rows,
+    16 bf16). Tables whose row counts are not multiples of that end in a
+    partial tile: ids in the first, second and last tile of each table must
+    come back exactly as the reference gives them."""
+    E, S, d, dl, dp = 13, 21, 16, 32, 8
+    ids = jnp.asarray([0, 7, 8, E - 1, E - 2, 5, 1, 9], jnp.int32)
+    sem_ids = jnp.asarray([S - 1, 0, 7, 8, 15, 16, S - 2, S - 1], jnp.int32)
+    h_str = jnp.asarray(rng.normal(size=(E, d)), dtype)
+    h_sem = jnp.asarray(rng.normal(size=(S, dl)), dtype)
+    wp = jnp.asarray(rng.normal(size=(dl, dp)) * 0.2, jnp.float32)
+    bp = jnp.asarray(rng.normal(size=(dp,)) * 0.1, jnp.float32)
+    wf = jnp.asarray(rng.normal(size=(d + dp, d)) * 0.2, jnp.float32)
+    bf = jnp.asarray(rng.normal(size=(d,)) * 0.1, jnp.float32)
+    out = ops.gather_fuse(ids, h_str, h_sem, wp, bp, wf, bf, sem_ids=sem_ids,
+                          rows=1, interpret=True)
+    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+    ref = gather_fuse_ref(jnp.arange(len(ids)), f32(h_str)[ids],
+                          f32(h_sem)[sem_ids], wp, bp, wf, bf)
+    tol = 1e-5 if dtype == np.float32 else 5e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               rtol=tol, atol=tol)
+
+
 def test_gather_fuse_matches_model_path(tiny_kg, rng):
     """The kernel must agree with QueryEncoder.fused_entity_vec (Eq. 12)."""
     import jax

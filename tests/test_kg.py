@@ -61,6 +61,19 @@ def test_table4_statistics():
     assert TABLE4["ATLAS-Wiki-Triple-4M"].n_relations == 512_064
     assert TABLE4["FB15k"].n_total == 592_213
 
+
+def test_full_scale_matches_table4():
+    from repro.data import load_dataset
+
+    train, full, stats = load_dataset("FB15k-237", reduced=False)
+    assert stats is TABLE4["FB15k-237"]
+    for kg in (train, full):
+        assert (kg.n_entities, kg.n_relations) == (14_505, 237)
+    assert len(train) == stats.n_train == 272_115
+    assert len(full) == stats.n_total == 272_115 + 17_526 + 20_438
+    # train is a subset of full: the other rows are the valid + test split
+    assert full.contains(train.triples).all()
+
 # ---------------------------------------------------------------------------
 # Live-write regression suite (DESIGN.md §LiveStore): the four write-path
 # bugs plus the snapshot/version surface they unblock.

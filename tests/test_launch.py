@@ -8,12 +8,10 @@ import pytest
 
 from repro.configs import ARCHS
 from repro.launch.roofline import (
-    HBM_BW,
-    ICI_BW,
-    PEAK_FLOPS,
     CollectiveStats,
     model_flops,
     parse_collectives,
+    peaks_for,
     roofline_terms,
     _type_bytes,
     _wire_bytes,
@@ -58,12 +56,23 @@ def test_wire_bytes_factors():
 
 
 def test_roofline_dominance():
-    r = roofline_terms(PEAK_FLOPS, HBM_BW * 0.5, ICI_BW * 2)
+    pk = peaks_for("TPU v5 lite")
+    r = roofline_terms(pk.flops, pk.hbm_bw * 0.5, pk.ici_bw * 2,
+                       "TPU v5 lite")
     assert r["compute_s"] == pytest.approx(1.0)
     assert r["memory_s"] == pytest.approx(0.5)
     assert r["collective_s"] == pytest.approx(2.0)
     assert r["dominant"] == "collective"
     assert r["roofline_fraction_compute"] == pytest.approx(0.5)
+
+
+def test_roofline_peaks_by_device_kind():
+    pk = peaks_for("TPU v5 lite")  # Google Cloud docs, "TPU v5e"
+    assert (pk.flops, pk.hbm_bw, pk.ici_bw) == (197e12, 819e9, 50e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks_for("TPU v4")
+    with pytest.raises(ValueError):
+        roofline_terms(1.0, 1.0, 1.0, "cpu")
 
 
 def test_model_flops_train_vs_decode():

@@ -27,6 +27,7 @@ pytestmark = pytest.mark.slow
 _PRELUDE = r"""
 import os, tempfile
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"  # emulated host devices only
 import sys; sys.path.insert(0, "src")
 import jax, numpy as np
 from repro.data import generate_synthetic_kg
