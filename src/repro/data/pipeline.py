@@ -24,6 +24,7 @@ items that exceed a deadline.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
 import threading
 import time
@@ -133,7 +134,9 @@ def batch_entity_ids(queries, pos: np.ndarray, neg: np.ndarray) -> np.ndarray:
 
 def prepare_work_item(sampler, executor, batch, n_negatives: int,
                       dev_static=None, sem_cache=None,
-                      ctx=None, mat_cache=None) -> "PreparedWorkItem":
+                      ctx=None, mat_cache=None, *, seq: Optional[int] = None,
+                      phases: Optional[dict] = None,
+                      counters: Optional[dict] = None) -> "PreparedWorkItem":
     """Run the full host side of one training step: negative-sampling arrays,
     plan compilation (canonicalize → CSE → Algorithm-1 lowering, i.e.
     ``executor.prepare`` returning a ``CompiledPlan``), and device transfer
@@ -173,7 +176,16 @@ def prepare_work_item(sampler, executor, batch, n_negatives: int,
     cross-thread lock discipline and surfaces reuse-potential counters,
     and inference consumers sharing the cache (eval after training, a
     co-located serving engine) get the rows the trainer's version bumps
-    keep honest."""
+    keep honest.
+
+    Every phase here is one span on the calling thread's lane (``negatives``,
+    ``sem_prefetch``, ``mat_probe``, ``schedule``, ``transfer``), each with
+    arg ``step`` = ``seq`` when given: the id joining one batch's spans
+    across the scheduler and dispatch lanes. ``transfer`` also carries
+    ``bytes``, the device bytes it created (``dev_static`` hits excluded).
+    Phase seconds go to ``phases`` (the item's ``phases``; a new dict when
+    None) and to ``counters[name]`` where given."""
+    import jax
     import jax.numpy as jnp  # deferred: keep module import light
 
     put = jnp.asarray
@@ -183,46 +195,51 @@ def prepare_work_item(sampler, executor, batch, n_negatives: int,
     # Per-phase wall times are ALWAYS collected (a perf_counter pair each —
     # nanoseconds against a multi-ms step) so step-time breakdowns work even
     # with the tracer off; the spans only fire when tracing is on.
-    phases = {}
-    t0 = time.perf_counter()
-    queries, pos, neg = sampler.to_training_arrays(batch, n_negatives)
-    phases["negatives_s"] = time.perf_counter() - t0
+    phases = {} if phases is None else phases
+    counters = counters or {}
+    ids = {} if seq is None else {"step": seq}
+
+    def timed(name, **args):
+        return TRACER.timed(name, phases, counters.get(name), **args, **ids)
+
+    with timed("negatives"):
+        queries, pos, neg = sampler.to_training_arrays(batch, n_negatives)
     sem_stage = None
     if sem_cache is not None:
-        t0 = time.perf_counter()
-        with TRACER.span("sem_prefetch", n=len(queries)):
+        with timed("sem_prefetch", n=len(queries)):
             sem_stage = sem_cache.plan(batch_entity_ids(queries, pos, neg),
                                        background=True)
-        phases["sem_prefetch_s"] = time.perf_counter() - t0
     mat_hits, mat_version = 0, -1
     if mat_cache is not None:
-        mat_version = mat_cache.version
-        mat_hits = mat_cache.probe([q.key() for q in queries],
-                                   version=mat_version)
-    t0 = time.perf_counter()
-    with TRACER.span("schedule", n=len(queries)):
+        with TRACER.span("mat_probe", n=len(queries), **ids):
+            mat_version = mat_cache.version
+            mat_hits = mat_cache.probe([q.key() for q in queries],
+                                       version=mat_version)
+    with timed("schedule", n=len(queries)):
         prepared = executor.prepare(queries)
-    phases["schedule_s"] = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    with TRACER.span("transfer", n_steps=len(prepared.bind_arrays)):
+    with timed("transfer", n_steps=len(prepared.bind_arrays)) as ph:
         static = (dev_static.get(prepared.structure_key)
                   if dev_static is not None else None)
+        created = []
         if static is None:
             static = (
                 [{k: put(v) for k, v in s.items()}
                  for s in prepared.slot_arrays],
                 put(prepared.answer_slots),
             )
+            created.append(static)
             if dev_static is not None:
                 dev_static.put(prepared.structure_key, static)
         slot_dev, ans = static
-        steps = [
-            {**s, **{k: put(v) for k, v in b.items()}}
-            for s, b in zip(slot_dev, prepared.bind_arrays)
-        ]
+        binds = [{k: put(v) for k, v in b.items()}
+                 for b in prepared.bind_arrays]
+        steps = [{**s, **b} for s, b in zip(slot_dev, binds)]
         pos_dev = put(pos[prepared.order])
         neg_dev = put(neg[prepared.order])
-    phases["transfer_s"] = time.perf_counter() - t0
+        if ph.span is not None:
+            ph.span.args["bytes"] = sum(
+                a.nbytes for a in jax.tree.leaves(
+                    (created, binds, pos_dev, neg_dev)))
     return PreparedWorkItem(
         prepared=prepared,
         steps=steps,
@@ -235,6 +252,7 @@ def prepare_work_item(sampler, executor, batch, n_negatives: int,
         mat_hits=mat_hits,
         mat_version=mat_version,
         phases=phases,
+        seq=seq,
     )
 
 
@@ -262,9 +280,11 @@ class PreparedWorkItem:
     mat_version: int = -1       # this cache version when the item was staged
     phases: dict = dataclasses.field(default_factory=dict)
     #                             scheduler-thread phase wall times (seconds):
-    #                             negatives_s/sem_prefetch_s/schedule_s/
-    #                             transfer_s (+ sample_s added by the
-    #                             prefetcher) — feeds step-time breakdowns
+    #                             sample_s/negatives_s/sem_prefetch_s/
+    #                             schedule_s/transfer_s — feeds step-time
+    #                             breakdowns
+    seq: Optional[int] = None   # the scheduler pass that built it: arg
+    #                             ``step`` of its spans on both lanes
 
 
 class PreparedBatchPrefetcher:
@@ -333,39 +353,42 @@ class PreparedBatchPrefetcher:
 
     def _run(self) -> None:
         TRACER.set_lane("pipeline scheduler")
-        while not self._stop.is_set():
+        # "sample" on this lane is the sampling itself when batch_fn runs
+        # inline; with sampling workers it is only the wait on their queue
+        # ("sample_wait": their own lanes carry the "sample" spans).
+        sample_span = "sample" if self._batches is None else "sample_wait"
+        for seq in itertools.count():
+            if self._stop.is_set():
+                return
             try:
-                t0 = time.perf_counter()
-                # "sample" on this lane is raw-batch acquisition: the
-                # sampling itself when batch_fn runs inline, queue wait on
-                # the workers otherwise (their own lanes carry the real
-                # sampling spans).
-                with TRACER.span("sample"):
+                phases = {}
+                with TRACER.timed(sample_span, phases, self._phase_s["sample"],
+                                  key="sample_s", step=seq):
                     batch = self._next_batch()
-                sample_s = time.perf_counter() - t0
                 item = prepare_work_item(self.sampler, self.executor, batch,
                                          self.n_negatives, self._dev_static,
                                          sem_cache=self.sem_cache,
                                          ctx=self.ctx,
-                                         mat_cache=self.mat_cache)
-                item.phases["sample_s"] = sample_s
-                for name, c in self._phase_s.items():
-                    c.inc(item.phases.get(name + "_s", 0.0))
+                                         mat_cache=self.mat_cache, seq=seq,
+                                         phases=phases,
+                                         counters=self._phase_s)
             except BaseException as e:  # surface on the consumer side
                 if self._error is None:
                     self._error = e
                 self._stop.set()
                 return
-            while not self._stop.is_set():
-                try:
-                    self._q.put(item, timeout=0.25)
-                    self._depth_gauge.set(self._q.qsize())
-                    if TRACER.enabled:
-                        TRACER.counter("prepared_q_depth",
-                                       depth=self._q.qsize())
-                    break
-                except queue.Full:
-                    continue
+            # Blocked here = the consumer (dispatch or device) sets the pace.
+            with TRACER.span("prepared_put", step=seq):
+                while not self._stop.is_set():
+                    try:
+                        self._q.put(item, timeout=0.25)
+                        self._depth_gauge.set(self._q.qsize())
+                        if TRACER.enabled:
+                            TRACER.counter("prepared_q_depth",
+                                           depth=self._q.qsize())
+                        break
+                    except queue.Full:
+                        continue
 
     def next(self, timeout: float = 120.0) -> PreparedWorkItem:
         while True:

@@ -11,7 +11,10 @@ Event model (the subset of the trace-event format Perfetto's JSON importer
 accepts):
 
 * ``ph:"X"`` complete events — a named span with ``ts``/``dur`` (µs since
-  tracer start), on the emitting thread's lane.
+  tracer start), on the emitting thread's lane, and ``tdur``: the CPU time
+  the thread spent inside it (``time.thread_time``, µs). ``dur - tdur`` is
+  the time the thread was inside its work but not running: waiting for the
+  GIL, a lock, a queue or the runtime.
 * ``ph:"M"`` metadata — ``thread_name`` per lane, emitted by
   :meth:`SpanTracer.set_lane` from each instrumented thread ("main
   dispatch", "pipeline scheduler", "sampling worker 0", "serving batcher").
@@ -19,11 +22,16 @@ accepts):
   ``id``; the serving engine opens one per request at submit and closes it
   at completion, so coalesced duplicates keep distinct request spans while
   sharing one batch/compute span.
-* ``ph:"i"`` instants and ``ph:"C"`` counters — flush triggers, queue depth.
+* ``ph:"C"`` counters — queue depth.
 
 Spans optionally bridge into ``jax.profiler.TraceAnnotation`` so the same
 names line up against device activity when a JAX profile is captured
 alongside.
+
+:meth:`SpanTracer.timed` times one phase of a step once and feeds every
+consumer of that interval from the one pair of clock reads: the caller's
+``phases`` dict (the ``--metrics`` step records), a registry counter
+(``*_phase_seconds{phase=...}``) and, when tracing is on, the span.
 
 Thread safety: events go into a plain list via ``list.append`` (GIL-atomic);
 lane registration takes a lock (rare). ``max_events`` caps memory — on
@@ -47,13 +55,14 @@ _NULL = contextlib.nullcontext()
 class _Span:
     """Context manager recording one ph:"X" event on the current lane."""
 
-    __slots__ = ("tracer", "name", "args", "t0", "_jax_ann")
+    __slots__ = ("tracer", "name", "args", "t0", "tt0", "_jax_ann")
 
-    def __init__(self, tracer: "SpanTracer", name: str, args: Optional[dict]):
+    def __init__(self, tracer: "SpanTracer", name: str, args: dict):
         self.tracer = tracer
         self.name = name
         self.args = args
         self.t0 = 0.0
+        self.tt0 = 0.0
         self._jax_ann = None
 
     def __enter__(self):
@@ -63,10 +72,18 @@ class _Span:
             if ann is not None:
                 ann.__enter__()
                 self._jax_ann = ann
+        # Thread-time reads nest inside the wall-clock pair: tdur <= dur.
         self.t0 = time.perf_counter()
+        self.tt0 = time.thread_time()
         return self
 
     def __exit__(self, *exc):
+        self.close(exc)
+        return False
+
+    def close(self, exc=(None, None, None)) -> float:
+        """End the span; returns its end (a ``perf_counter`` reading)."""
+        tdur = time.thread_time() - self.tt0
         t1 = time.perf_counter()
         if self._jax_ann is not None:
             self._jax_ann.__exit__(*exc)
@@ -74,10 +91,47 @@ class _Span:
         ev = {"name": self.name, "ph": "X", "pid": tr.pid,
               "tid": tr.lane_tid(),
               "ts": (self.t0 - tr.epoch) * 1e6,
-              "dur": (t1 - self.t0) * 1e6, "cat": "repro"}
+              "dur": (t1 - self.t0) * 1e6, "tdur": tdur * 1e6,
+              "cat": "repro"}
         if self.args:
             ev["args"] = self.args
         tr._emit(ev)
+        return t1
+
+
+class _Phase:
+    """One timed phase (:meth:`SpanTracer.timed`): a single
+    ``perf_counter`` pair feeds ``phases[key]``, ``counter`` and, when
+    tracing was on at entry, the span (``span``; None otherwise)."""
+
+    __slots__ = ("span", "phases", "key", "counter", "t0", "seconds")
+
+    def __init__(self, span: Optional[_Span], phases: Optional[dict],
+                 key: str, counter):
+        self.span = span
+        self.phases = phases
+        self.key = key
+        self.counter = counter
+        self.t0 = 0.0
+        self.seconds = 0.0
+
+    def __enter__(self):
+        if self.span is not None:
+            self.t0 = self.span.__enter__().t0
+        else:
+            self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        if self.span is not None:
+            t1 = self.span.close(exc)
+        else:
+            t1 = time.perf_counter()
+        self.seconds = t1 - self.t0
+        if self.phases is not None:
+            self.phases[self.key] = self.seconds
+        if self.counter is not None:
+            self.counter.inc(self.seconds)
         return False
 
 
@@ -160,17 +214,19 @@ class SpanTracer:
         Returns a shared null context when tracing is off (the fast path)."""
         if not self.enabled:
             return _NULL
-        return _Span(self, name, args or None)
+        return _Span(self, name, args)
 
-    def instant(self, name: str, **args) -> None:
-        if not self.enabled:
-            return
-        ev = {"name": name, "ph": "i", "s": "t", "pid": self.pid,
-              "tid": self.lane_tid(),
-              "ts": (time.perf_counter() - self.epoch) * 1e6, "cat": "repro"}
-        if args:
-            ev["args"] = args
-        self._emit(ev)
+    def timed(self, name: str, phases: Optional[dict] = None, counter=None,
+              *, key: Optional[str] = None, **args) -> _Phase:
+        """Context manager timing one phase once: on exit its seconds go to
+        ``phases[key]`` (``key`` defaults to ``name + "_s"``) and to
+        ``counter.inc``, and, when tracing is on, the same interval is the
+        span ``name`` with ``args``. Either consumer may be None. The
+        manager's ``seconds`` holds the phase's length after exit, and its
+        ``span`` is None when tracing is off, so a caller computes an extra
+        span arg only when it is recorded."""
+        span = _Span(self, name, args) if self.enabled else None
+        return _Phase(span, phases, key or name + "_s", counter)
 
     def counter(self, name: str, **values) -> None:
         """ph:"C" counter track (queue depth, batch occupancy over time)."""
@@ -235,7 +291,6 @@ TRACER = SpanTracer()
 
 _REQUIRED = {"X": ("name", "ph", "ts", "dur", "pid", "tid"),
              "M": ("name", "ph", "pid", "tid", "args"),
-             "i": ("name", "ph", "ts", "pid", "tid"),
              "C": ("name", "ph", "ts", "pid", "tid", "args"),
              "b": ("name", "ph", "ts", "id", "pid", "tid"),
              "e": ("name", "ph", "ts", "id", "pid", "tid")}
@@ -247,7 +302,8 @@ def validate_trace(obj: Any) -> Dict[str, Any]:
     summary (``lanes``, ``names``, per-phase ``counts``, async balance).
 
     Checks: top-level ``traceEvents`` list; every event has the required
-    keys for its phase with numeric ``ts``/``dur`` (``dur >= 0``);
+    keys for its phase with numeric ``ts``/``dur`` (``dur >= 0``, and
+    ``tdur >= 0`` where present);
     ``thread_name`` metadata carries ``args.name``; ``b``/``e`` events
     balance per (cat, id) with begin-before-end; JSON-serializability.
     """
@@ -286,6 +342,9 @@ def validate_trace(obj: Any) -> Dict[str, Any]:
             dur = ev["dur"]
             if not isinstance(dur, (int, float)) or dur < 0:
                 raise ValueError(f"event {i}: bad dur {dur!r}")
+            tdur = ev.get("tdur", 0.0)
+            if not isinstance(tdur, (int, float)) or tdur < 0:
+                raise ValueError(f"event {i}: bad tdur {tdur!r}")
         names.add(ev["name"])
         if ph == "b":
             key = (ev.get("cat"), ev["id"], ev["name"])

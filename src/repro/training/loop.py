@@ -292,37 +292,30 @@ class NGDBTrainer:
         if self.sem_cache is not None:
             # Sync mode stages on the critical path (the pipelined loop does
             # this on the scheduler thread instead — zero mid-step reads).
-            tp = time.perf_counter()
-            with TRACER.span("sem_prefetch"):
+            with TRACER.timed("sem_prefetch", phases):
                 stage = self.sem_cache.plan(batch_entity_ids(queries, pos, neg))
-            if stage is not None:
-                self.params = self.sem_cache.apply_to(self.params, stage)
-            phases["sem_prefetch_s"] = time.perf_counter() - tp
+                if stage is not None:
+                    self.params = self.sem_cache.apply_to(self.params, stage)
         t0 = time.perf_counter()
         if isinstance(self.executor, PooledExecutor):
-            with TRACER.span("schedule", n=len(queries)):
+            with TRACER.timed("schedule", phases, n=len(queries)):
                 prepared = self.executor.prepare(queries)
-            phases["schedule_s"] = time.perf_counter() - t0
             pos = pos[prepared.order]
             neg = neg[prepared.order]
             steps, ans = prepared.device_args()
             # A signature absent from the cache means THIS dispatch pays the
             # jit trace+compile — label the span accordingly.
-            cold = prepared.signature not in self._train_fns
+            key = ("compile" if prepared.signature not in self._train_fns
+                   else "dispatch")
             fn = self._train_fn(prepared, example=(steps, ans, pos, neg))
-            td = time.perf_counter()
             # pos/neg go in as host numpy: the jit places them per its
             # in_shardings (one transfer straight into the compiled layout);
             # a jnp.asarray here would commit to device 0 first and force a
             # second reshard transfer at dispatch under a mesh ctx.
-            with TRACER.span("compile" if cold else "dispatch"):
+            with TRACER.timed(key, phases, self._phase_s[key]):
                 self.params, self.opt_state, loss, per_q = fn(
                     self.params, self.opt_state, steps, ans, pos, neg
                 )
-            phases["compile_s" if cold else "dispatch_s"] = (
-                time.perf_counter() - td)
-            self._phase_s["compile" if cold else "dispatch"].inc(
-                phases["compile_s" if cold else "dispatch_s"])
             patterns = prepared.patterns
         else:  # query-level baseline: one fragmented pass per pattern group
             loss, per_q, patterns = self._query_level_step(queries, pos, neg)
@@ -331,11 +324,8 @@ class NGDBTrainer:
             # params must never be served (or inserted: version pinning in
             # insert() drops in-flight encodes started before this bump).
             self.mat_cache.bump_version("param_update")
-        tr = time.perf_counter()
-        with TRACER.span("retire"):
+        with TRACER.timed("retire", phases, self._phase_s["retire"]):
             loss = float(loss)
-        phases["retire_s"] = time.perf_counter() - tr
-        self._phase_s["retire"].inc(phases["retire_s"])
         self._steps_done.inc()
         if self.adaptive:
             self.adaptive.update(pattern_losses_from_batch(patterns, per_q))
@@ -562,39 +552,35 @@ class NGDBTrainer:
         TRACER.set_lane("main dispatch")
         try:
             for _ in range(n_steps):
-                tw = time.perf_counter()
                 # This wait IS the pipeline bubble: the prefetcher had no
                 # ready item, so the main thread idles instead of dispatching.
-                with TRACER.span("pipeline_wait"):
+                with TRACER.timed("pipeline_wait", None,
+                                  self._phase_s["pipeline_wait"]) as wait:
                     item = pf.next()
-                wait_s = time.perf_counter() - tw
-                item.phases["wait_s"] = wait_s
-                self._phase_s["pipeline_wait"].inc(wait_s)
+                item.phases["wait_s"] = wait.seconds
                 if item.sem_stage is not None:
                     # The scheduler thread already did the store read +
                     # device put (overlapped with step k); this is just the
                     # donated scatter, enqueued after step k's program — the
                     # in-order device stream makes eviction of step k's rows
                     # safe even while k is still executing.
-                    ta = time.perf_counter()
-                    with TRACER.span("sem_apply"):
+                    with TRACER.timed("sem_apply", item.phases,
+                                      self._phase_s["sem_apply"],
+                                      step=item.seq):
                         self.params = self.sem_cache.apply_to(self.params,
                                                               item.sem_stage)
-                    item.phases["sem_apply_s"] = time.perf_counter() - ta
-                    self._phase_s["sem_apply"].inc(item.phases["sem_apply_s"])
-                cold = item.prepared.signature not in self._train_fns
+                key = ("compile"
+                       if item.prepared.signature not in self._train_fns
+                       else "dispatch")
                 fn = self._train_fn(item.prepared,
                                     example=(item.steps, item.ans,
                                              item.pos, item.neg))
-                td = time.perf_counter()
-                with TRACER.span("compile" if cold else "dispatch"):
+                with TRACER.timed(key, item.phases, self._phase_s[key],
+                                  step=item.seq):
                     self.params, self.opt_state, loss, per_q = fn(
                         self.params, self.opt_state, item.steps, item.ans,
                         item.pos, item.neg,
                     )
-                key = "compile" if cold else "dispatch"
-                item.phases[key + "_s"] = time.perf_counter() - td
-                self._phase_s[key].inc(item.phases[key + "_s"])
                 if self.mat_cache is not None:
                     # Dispatch replaced the params handle; scheduler-thread
                     # probes pinned to the old version stop matching and any

@@ -210,7 +210,9 @@ def test_disabled_tracer_is_free_and_silent():
     assert s1 is s2  # shared null context: the one-attribute-read fast path
     with s1:
         pass
-    TRACER.instant("x")
+    with TRACER.timed("c", {}, None, n=2) as ph:
+        pass
+    assert ph.span is None  # no span object, no clock read beyond the phase
     TRACER.counter("q", depth=3)
     TRACER.async_begin("r", 1)
     TRACER.async_end("r", 1)
@@ -277,8 +279,10 @@ def test_set_lane_survives_enable():
 
 def test_max_events_truncation():
     TRACER.enable(jax_annotations=False, max_events=3)
-    for i in range(10):
-        TRACER.instant(f"e{i}")
+    for i in range(5):
+        with TRACER.span(f"e{i}"):
+            pass
+        TRACER.counter(f"c{i}", depth=i)
     obj = TRACER.to_json()
     # metadata (lane names) is exempt from the cap; data events are capped
     data = [e for e in obj["traceEvents"] if e["ph"] != "M"]
@@ -401,6 +405,112 @@ def test_pipelined_trainer_writes_bubble_fraction(tiny_kg, tmp_path):
         assert 0.0 <= r["bubble_frac"] <= 1.0
         assert r["wall_s"] > 0
         assert "wait_s" in r and "schedule_s" in r and "transfer_s" in r
+
+
+@pytest.fixture(scope="module")
+def scheduler_trace(tiny_kg):
+    """A pipelined ``train`` of 6 steps on a pinned batch (``batch_fn``
+    given: the batch is drawn inline on the scheduler lane), traced; the
+    trace object and the X events of each lane by lane name."""
+    from repro.sampling import OnlineSampler
+
+    batches = [OnlineSampler(tiny_kg, seed=23).sample_batch(8)]
+    tr = _trainer(tiny_kg, pipeline=True)
+    TRACER.enable(jax_annotations=False)
+    try:
+        tr.train(6, log_every=0, batches=batches)
+        obj = TRACER.to_json()
+    finally:
+        TRACER.disable()
+    lanes = {e["tid"]: e["args"]["name"] for e in obj["traceEvents"]
+             if e["ph"] == "M"}
+    by_lane = {}
+    for e in obj["traceEvents"]:
+        if e["ph"] == "X":
+            by_lane.setdefault(lanes.get(e["tid"], ""), []).append(e)
+    return obj, by_lane
+
+
+def test_scheduler_spans_join_each_dispatched_step(scheduler_trace):
+    """Every dispatched step id has one span of each producer phase on the
+    scheduler lane and one dispatch/compile span on the main lane; transfer
+    spans carry the device bytes they created."""
+    obj, by_lane = scheduler_trace
+    validate_trace(obj)
+    main, sched = by_lane["main dispatch"], by_lane["pipeline scheduler"]
+    dispatched = [e["args"]["step"] for e in main
+                  if e["name"] in ("dispatch", "compile")]
+    assert len(dispatched) == 6 and len(set(dispatched)) == 6
+    for step in dispatched:
+        names = sorted(e["name"] for e in sched
+                       if e.get("args", {}).get("step") == step)
+        assert names == ["negatives", "prepared_put", "sample", "schedule",
+                         "transfer"], (step, names)
+    transfers = [e for e in sched if e["name"] == "transfer"]
+    # pos [8] + neg [8, 4] int32 at least, on every batch
+    assert all(e["args"]["bytes"] >= 8 * 5 * 4 for e in transfers)
+
+
+def test_scheduler_lane_is_covered_by_spans(scheduler_trace):
+    """The scheduler lane's spans cover >= 90% of its wall time from its
+    first span's start to its last span's end: no unnamed hole."""
+    _, by_lane = scheduler_trace
+    sched = sorted(by_lane["pipeline scheduler"], key=lambda e: e["ts"])
+    lo = sched[0]["ts"]
+    hi = max(e["ts"] + e["dur"] for e in sched)
+    covered, end = 0.0, lo
+    for e in sched:  # union of the intervals
+        a, b = max(e["ts"], end), e["ts"] + e["dur"]
+        if b > a:
+            covered += b - a
+            end = b
+    assert covered >= 0.9 * (hi - lo), (covered, hi - lo)
+
+
+def test_span_thread_time_is_within_wall_time(scheduler_trace):
+    """``tdur`` (thread CPU time) of every span lies in [0, dur + 50 us],
+    and the validator rejects a negative one."""
+    obj, _ = scheduler_trace
+    spans = [e for e in obj["traceEvents"] if e["ph"] == "X"]
+    assert spans and all("tdur" in e for e in spans)
+    for e in spans:
+        assert 0.0 <= e["tdur"] <= e["dur"] + 50.0, e
+    with pytest.raises(ValueError, match="bad tdur"):
+        validate_trace({"traceEvents": [{"name": "a", "ph": "X", "ts": 0.0,
+                                         "dur": 1.0, "tdur": -1.0, "pid": 1,
+                                         "tid": 1}]})
+
+
+def test_pipelined_train_records_nothing_with_tracing_off(tiny_kg):
+    from repro.sampling import OnlineSampler
+
+    TRACER.enable(jax_annotations=False)
+    TRACER.disable()  # enable() cleared the buffer
+    batches = [OnlineSampler(tiny_kg, seed=23).sample_batch(8)]
+    tr = _trainer(tiny_kg, pipeline=True)
+    tr.train(3, log_every=0, batches=batches)
+    assert TRACER._events == []
+    assert TRACER.span("a") is TRACER.span("b")
+
+
+def test_worker_fed_scheduler_waits_in_sample_wait(tiny_kg):
+    """With sampling workers, the scheduler lane only waits on their queue:
+    its span is ``sample_wait``; the workers' lanes carry ``sample``."""
+    tr = _trainer(tiny_kg, pipeline=True)
+    TRACER.enable(jax_annotations=False)
+    tr.train(3, log_every=0)
+    obj = TRACER.to_json()
+    TRACER.disable()
+    lanes = {e["tid"]: e["args"]["name"] for e in obj["traceEvents"]
+             if e["ph"] == "M"}
+    names = {}
+    for e in obj["traceEvents"]:
+        if e["ph"] == "X":
+            names.setdefault(lanes.get(e["tid"], ""), set()).add(e["name"])
+    assert "sample_wait" in names["pipeline scheduler"]
+    assert "sample" not in names["pipeline scheduler"]
+    assert any("sample" in v for k, v in names.items()
+               if k.startswith("sampling worker"))
 
 
 def test_phase_counters_register_in_snapshot(tiny_kg, mixed_queries):
