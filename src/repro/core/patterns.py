@@ -114,7 +114,8 @@ class QueryInstance:
 
 def answer_query(kg: KnowledgeGraph, q: QueryInstance) -> Set[int]:
     """Symbolic (set-semantics) evaluation — the ground-truth oracle used by
-    the sampler for rejection sampling and by tests as the logic oracle."""
+    evaluation and by tests as the logic oracle (the sampler's rejection
+    check is ``answer_array``, which shares no code with it)."""
     tpl = TEMPLATES[q.pattern]
     sets: List[Set[int]] = [set()] * len(tpl.nodes)
     negated: List[bool] = [False] * len(tpl.nodes)
@@ -144,5 +145,80 @@ def answer_query(kg: KnowledgeGraph, q: QueryInstance) -> Set[int]:
             acc = set()
             for j in node.inputs:
                 acc |= sets[j]
+            sets[i] = acc
+    return sets[tpl.answer_node]
+
+
+_EMPTY = np.empty(0, dtype=np.int64)
+
+
+def _project_array(adj, n_relations: int, heads: np.ndarray, r: int) -> np.ndarray:
+    """Sorted unique tails of (h, r, ·) over sorted unique ``heads``."""
+    if len(heads) == 1:
+        hr = int(heads[0]) * n_relations + r
+        span = adj.tails[adj.hr.searchsorted(hr, "left"):adj.hr.searchsorted(hr, "right")]
+        span.flags.writeable = False  # one (h, r) span: sorted, deduped, the graph's own
+        return span
+    if len(heads) == 0:
+        return _EMPTY
+    hr = heads * n_relations + r
+    lo = adj.hr.searchsorted(hr, side="left")
+    n = adj.hr.searchsorted(hr, side="right") - lo
+    total = int(n.sum())
+    if total == 0:
+        return _EMPTY
+    # All spans in one gather: each span's start repeated over its length,
+    # plus the offset within the span.
+    ends = np.cumsum(n)
+    idx = np.repeat(lo - (ends - n), n) + np.arange(total)
+    return np.unique(adj.tails[idx])
+
+
+def _found_in(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """Mask of ``a``'s members present in ``b``; both sorted unique, ``b``
+    non-empty. One binary search per member of ``a``, no sort."""
+    j = b.searchsorted(a)
+    j[j == len(b)] = 0
+    return b[j] == a
+
+
+def answer_array(kg: KnowledgeGraph, q: QueryInstance) -> np.ndarray:
+    """``answer_query`` on sorted unique int64 arrays: the same template DAG
+    and semantics, the sampler's rejection oracle. Reads the adjacency once,
+    so a concurrent graph write cannot tear one evaluation."""
+    adj = kg._adj
+    R = kg.n_relations
+    tpl = TEMPLATES[q.pattern]
+    sets: List[np.ndarray] = [_EMPTY] * len(tpl.nodes)
+    negated = [False] * len(tpl.nodes)
+    a_i = 0
+    r_i = 0
+    for i, node in enumerate(tpl.nodes):
+        if node.op == OpType.EMBED:
+            sets[i] = q.anchors[a_i:a_i + 1]
+            a_i += 1
+        elif node.op == OpType.PROJECT:
+            sets[i] = _project_array(adj, R, sets[node.inputs[0]], int(q.relations[r_i]))
+            r_i += 1
+        elif node.op == OpType.NEGATE:
+            sets[i] = sets[node.inputs[0]]
+            negated[i] = True
+        elif node.op == OpType.INTERSECT:
+            pos = [sets[j] for j in node.inputs if not negated[j]]
+            acc = pos[0]
+            for s in pos[1:]:
+                if len(acc) == 0 or len(s) == 0:
+                    acc = _EMPTY
+                    break
+                small, large = (acc, s) if len(acc) <= len(s) else (s, acc)
+                acc = small[_found_in(small, large)]
+            for j in node.inputs:
+                if negated[j] and len(acc) and len(sets[j]):
+                    acc = acc[~_found_in(acc, sets[j])]
+            sets[i] = acc
+        elif node.op == OpType.UNION:
+            acc = sets[node.inputs[0]]
+            for j in node.inputs[1:]:
+                acc = np.union1d(acc, sets[j])
             sets[i] = acc
     return sets[tpl.answer_node]

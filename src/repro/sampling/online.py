@@ -10,13 +10,33 @@ rejection sampling against the symbolic oracle (P_accept ∝ 1[q ∈ Q_valid]).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.ops import OpType
-from repro.core.patterns import TEMPLATES, QueryInstance, answer_query
+from repro.core.patterns import TEMPLATES, QueryInstance, answer_array
 from repro.data.kg import KnowledgeGraph
+
+
+def _ground_plan(tpl) -> Tuple:
+    """A template as ``_ground`` walks it: (n nodes, reverse walk of
+    (node, op, inputs), EMBED nodes, PROJECT nodes). An INTERSECT lists only
+    its positive inputs: negated ones stay unconstrained."""
+    walk = []
+    for i in range(len(tpl.nodes) - 1, -1, -1):
+        node = tpl.nodes[i]
+        inputs = node.inputs
+        if node.op == OpType.INTERSECT:
+            inputs = tuple(j for j in inputs if tpl.nodes[j].op != OpType.NEGATE)
+        walk.append((i, node.op, inputs))
+    ops = [nd.op for nd in tpl.nodes]
+    return (len(ops), tuple(walk),
+            tuple(i for i, op in enumerate(ops) if op == OpType.EMBED),
+            tuple(i for i, op in enumerate(ops) if op == OpType.PROJECT))
+
+
+_GROUND_PLANS = {name: _ground_plan(tpl) for name, tpl in TEMPLATES.items()}
 
 
 @dataclasses.dataclass
@@ -46,13 +66,27 @@ class OnlineSampler:
         cand = kg.entities_with_incoming
         if degree_weighted:
             w = kg.degree[cand].astype(np.float64)
-            self._answer_p = w / w.sum()
+            # Generator.choice(cand, p=w / w.sum())'s own table, built once
+            # instead of on every draw: the same index for the same
+            # generator state, one rng.random() per draw.
+            cdf = (w / w.sum()).cumsum()
+            cdf /= cdf[-1]
+            self._answer_cdf = cdf
         else:
-            self._answer_p = None
+            self._answer_cdf = None
         self._answer_cand = cand
-        self.stats = {"sampled": 0, "rejected": 0}
+        # table_draws: weighted answer/witness draws served by the table.
+        self.stats = {"sampled": 0, "rejected": 0, "table_draws": 0}
 
     # ------------------------------------------------------------- grounding
+    def _draw_answer(self) -> int:
+        """A witness entity, degree-weighted unless built otherwise."""
+        if self._answer_cdf is None:
+            return int(self.rng.choice(self._answer_cand))
+        self.stats["table_draws"] += 1
+        i = self._answer_cdf.searchsorted(self.rng.random(), side="right")
+        return int(self._answer_cand[i])
+
     def _random_incoming(self, ent: int) -> Optional[Tuple[int, int]]:
         lo, hi = self._in_indptr[ent], self._in_indptr[ent + 1]
         if hi <= lo:
@@ -61,44 +95,32 @@ class OnlineSampler:
         return int(self._in_rels[j]), int(self._in_heads[j])
 
     def _ground(self, pattern: str) -> Optional[QueryInstance]:
-        tpl = TEMPLATES[pattern]
-        n = len(tpl.nodes)
-        ent = np.full(n, -1, dtype=np.int64)
-        rel_of_node = np.full(n, -1, dtype=np.int64)
-        target = int(self.rng.choice(self._answer_cand, p=self._answer_p))
-        ent[tpl.answer_node] = target
+        n, walk, embeds, projects = _GROUND_PLANS[pattern]
+        ent = [-1] * n
+        rel_of_node = [-1] * n
+        ent[n - 1] = self._draw_answer()  # the answer node is the last
         # Reverse walk: every node's witness entity is known before its inputs.
-        for i in range(n - 1, -1, -1):
-            node = tpl.nodes[i]
+        for i, op, inputs in walk:
             if ent[i] < 0:
                 # Unconstrained branch (e.g. the negated side): random witness.
-                ent[i] = int(self.rng.choice(self._answer_cand, p=self._answer_p))
-            if node.op == OpType.PROJECT:
-                step = self._random_incoming(int(ent[i]))
+                ent[i] = self._draw_answer()
+            if op is OpType.PROJECT:
+                step = self._random_incoming(ent[i])
                 if step is None:
                     return None
-                rel_of_node[i], ent[node.inputs[0]] = step
-            elif node.op == OpType.INTERSECT:
-                for j in node.inputs:
-                    # Negated inputs stay unconstrained; positive inputs share
-                    # the witness so the intersection is non-empty.
-                    if tpl.nodes[j].op != OpType.NEGATE:
-                        ent[j] = ent[i]
-            elif node.op == OpType.UNION:
-                k = node.inputs[int(self.rng.integers(len(node.inputs)))]
+                rel_of_node[i], ent[inputs[0]] = step
+            elif op is OpType.INTERSECT:
+                for j in inputs:  # the positive inputs share the witness
+                    ent[j] = ent[i]
+            elif op is OpType.UNION:
+                k = inputs[int(self.rng.integers(len(inputs)))]
                 ent[k] = ent[i]  # one branch witnesses; others stay random
-            elif node.op == OpType.NEGATE:
-                pass  # input grounded independently (stays -1 → random)
-        anchors = np.array(
-            [ent[i] for i, nd in enumerate(tpl.nodes) if nd.op == OpType.EMBED], dtype=np.int64
+            # NEGATE: its input is grounded independently (stays -1 → random)
+        return QueryInstance(
+            pattern,
+            np.array([ent[i] for i in embeds], dtype=np.int64),
+            np.array([rel_of_node[i] for i in projects], dtype=np.int64),
         )
-        rels = np.array(
-            [rel_of_node[i] for i, nd in enumerate(tpl.nodes) if nd.op == OpType.PROJECT],
-            dtype=np.int64,
-        )
-        if (anchors < 0).any() or (rels < 0).any():
-            return None
-        return QueryInstance(pattern, anchors, rels)
 
     # ------------------------------------------------------------- sampling
     def sample(self, pattern: str) -> SampledQuery:
@@ -108,11 +130,10 @@ class OnlineSampler:
             if q is None:
                 self.stats["rejected"] += 1
                 continue
-            ans = answer_query(self.kg, q)
-            if not ans:  # rejection sampling: require non-empty answer set
+            ans_arr = answer_array(self.kg, q)  # sorted, unique
+            if len(ans_arr) == 0:  # rejection sampling: require non-empty answer set
                 self.stats["rejected"] += 1
                 continue
-            ans_arr = np.fromiter(ans, dtype=np.int64)
             if len(ans_arr) > self.max_answers:
                 ans_arr = self.rng.choice(ans_arr, self.max_answers, replace=False)
             return SampledQuery(q, ans_arr)

@@ -9,6 +9,9 @@ from repro.core import (
     answer_query,
     build_batched_dag,
 )
+from repro.core import patterns as patterns_mod
+from repro.core.patterns import answer_array
+from repro.data import generate_synthetic_kg
 
 
 def test_fourteen_patterns():
@@ -67,6 +70,55 @@ def test_answer_query_up(tiny_kg):
         tiny_kg.neighbors_of_set(np.fromiter(u, dtype=np.int64), 2).tolist()
     )
     assert answer_query(tiny_kg, q) == expected
+
+
+@pytest.fixture(scope="module")
+def zipf_kg():
+    return generate_synthetic_kg(2000, 20, 30000, seed=1, hub_exponent=1.0)
+
+
+def _mixed_instances(kg, pattern, seed):
+    """Sampled queries (non-empty answers) and uniformly drawn ones (mostly
+    empty) of one template."""
+    from repro.sampling import OnlineSampler
+
+    tpl = TEMPLATES[pattern]
+    sampler = OnlineSampler(kg, patterns=[pattern], seed=seed)
+    qs = [sampler.sample(pattern).query for _ in range(20)]
+    rng = np.random.default_rng(seed)
+    for _ in range(40):
+        qs.append(QueryInstance(
+            pattern,
+            rng.integers(0, kg.n_entities, tpl.n_anchors),
+            rng.integers(0, kg.n_relations, tpl.n_relations)))
+    return qs
+
+
+@pytest.mark.parametrize("pattern", PATTERN_NAMES)
+def test_answer_array_matches_answer_query(pattern, tiny_kg, zipf_kg, monkeypatch):
+    head_counts = []
+    project = patterns_mod._project_array
+
+    def spy(adj, n_relations, heads, r):
+        head_counts.append(len(heads))
+        return project(adj, n_relations, heads, r)
+
+    monkeypatch.setattr(patterns_mod, "_project_array", spy)
+    n_empty = n_full = 0
+    for kg, seed in ((tiny_kg, 5), (zipf_kg, 6)):
+        for q in _mixed_instances(kg, pattern, seed):
+            got = answer_array(kg, q)
+            assert got.dtype == np.int64 and got.ndim == 1
+            assert np.all(np.diff(got) > 0)  # sorted, unique
+            assert set(got.tolist()) == answer_query(kg, q)
+            n_empty += len(got) == 0
+            n_full += len(got) > 0
+    assert n_empty and n_full
+    tpl = TEMPLATES[pattern]
+    chained = any(tpl.nodes[n.inputs[0]].op != OpType.EMBED
+                  for n in tpl.nodes if n.op == OpType.PROJECT)
+    if chained:  # a projection over a set of several heads was evaluated
+        assert max(head_counts) > 1
 
 
 def test_dag_merge_counts(mixed_queries):

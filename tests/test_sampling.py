@@ -1,7 +1,8 @@
 import numpy as np
 import pytest
 
-from repro.core import PATTERN_NAMES, answer_query
+from repro.core import PATTERN_NAMES, TEMPLATES, OpType, QueryInstance, answer_query
+from repro.data import generate_synthetic_kg
 from repro.sampling import AdaptiveDistribution, OnlineSampler
 
 
@@ -12,7 +13,6 @@ def test_all_patterns_sampleable(tiny_kg):
         assert sq.query.pattern == pat
         assert len(sq.answers) > 0
         # rejection guarantee: oracle agrees the answers are non-empty
-        assert answer_query(tiny_kg, sq.query) >= set(sq.answers.tolist()) or True
         assert set(sq.answers.tolist()) <= answer_query(tiny_kg, sq.query)
 
 
@@ -49,3 +49,131 @@ def test_sampler_determinism(tiny_kg):
     b = OnlineSampler(tiny_kg, seed=42).sample_batch(8)
     for x, y in zip(a, b):
         assert x.query.key() == y.query.key()
+
+
+class _SetOracleSampler:
+    """The sampler as it was before its answer table and array oracle:
+    ``Generator.choice(..., p=...)`` per witness draw and rejection on
+    ``answer_query``'s Python sets. The reference stream for the tests
+    below."""
+
+    def __init__(self, kg, patterns, seed, max_answers, degree_weighted):
+        self.kg = kg
+        self.patterns = list(patterns)
+        self.rng = np.random.default_rng(seed)
+        self.max_answers = max_answers
+        self._in_indptr, self._in_rels, self._in_heads = kg.incoming_by_tail
+        cand = kg.entities_with_incoming
+        if degree_weighted:
+            w = kg.degree[cand].astype(np.float64)
+            self._answer_p = w / w.sum()
+        else:
+            self._answer_p = None
+        self._answer_cand = cand
+        self.stats = {"sampled": 0, "rejected": 0}
+        self.weighted_draws = 0
+
+    def _witness(self):
+        self.weighted_draws += self._answer_p is not None
+        return int(self.rng.choice(self._answer_cand, p=self._answer_p))
+
+    def _random_incoming(self, ent):
+        lo, hi = self._in_indptr[ent], self._in_indptr[ent + 1]
+        if hi <= lo:
+            return None
+        j = int(self.rng.integers(lo, hi))
+        return int(self._in_rels[j]), int(self._in_heads[j])
+
+    def _ground(self, pattern):
+        tpl = TEMPLATES[pattern]
+        n = len(tpl.nodes)
+        ent = np.full(n, -1, dtype=np.int64)
+        rel_of_node = np.full(n, -1, dtype=np.int64)
+        ent[tpl.answer_node] = self._witness()
+        for i in range(n - 1, -1, -1):
+            node = tpl.nodes[i]
+            if ent[i] < 0:
+                ent[i] = self._witness()
+            if node.op == OpType.PROJECT:
+                step = self._random_incoming(int(ent[i]))
+                if step is None:
+                    return None
+                rel_of_node[i], ent[node.inputs[0]] = step
+            elif node.op == OpType.INTERSECT:
+                for j in node.inputs:
+                    if tpl.nodes[j].op != OpType.NEGATE:
+                        ent[j] = ent[i]
+            elif node.op == OpType.UNION:
+                k = node.inputs[int(self.rng.integers(len(node.inputs)))]
+                ent[k] = ent[i]
+        anchors = np.array(
+            [ent[i] for i, nd in enumerate(tpl.nodes) if nd.op == OpType.EMBED], dtype=np.int64)
+        rels = np.array(
+            [rel_of_node[i] for i, nd in enumerate(tpl.nodes) if nd.op == OpType.PROJECT],
+            dtype=np.int64)
+        if (anchors < 0).any() or (rels < 0).any():
+            return None
+        return QueryInstance(pattern, anchors, rels)
+
+    def sample(self, pattern):
+        for _ in range(32):
+            self.stats["sampled"] += 1
+            q = self._ground(pattern)
+            if q is None:
+                self.stats["rejected"] += 1
+                continue
+            ans = answer_query(self.kg, q)
+            if not ans:
+                self.stats["rejected"] += 1
+                continue
+            ans_arr = np.fromiter(ans, dtype=np.int64)
+            if len(ans_arr) > self.max_answers:
+                ans_arr = self.rng.choice(ans_arr, self.max_answers, replace=False)
+            return q, ans_arr
+        raise RuntimeError(pattern)
+
+    def sample_batch(self, batch_size):
+        picks = self.rng.choice(len(self.patterns), size=batch_size)
+        return [self.sample(self.patterns[i]) for i in picks]
+
+
+@pytest.fixture(scope="module")
+def hub_kg():
+    """Few relations and steep hubs: large answer sets and rejections."""
+    return generate_synthetic_kg(400, 6, 5000, seed=7, hub_exponent=1.3)
+
+
+@pytest.mark.parametrize("degree_weighted", [True, False])
+@pytest.mark.parametrize("seed", [0, 11, 2**31 + 5])
+def test_query_stream_matches_set_oracle_sampler(hub_kg, seed, degree_weighted):
+    max_answers = 24  # small, so sub-sampled answer sets occur in the stream
+    new = OnlineSampler(hub_kg, seed=seed, max_answers=max_answers,
+                        degree_weighted=degree_weighted)
+    old = _SetOracleSampler(hub_kg, PATTERN_NAMES, seed, max_answers, degree_weighted)
+    got, want = [], []
+    for round_ in range(3):
+        for pat in PATTERN_NAMES:
+            got.append(new.sample(pat))
+            want.append(old.sample(pat))
+        got.extend(new.sample_batch(40))
+        want.extend(old.sample_batch(40))
+    n_capped = 0
+    for sq, (q, ans) in zip(got, want):
+        assert sq.query.key() == q.key()
+        full = answer_query(hub_kg, q)
+        if len(full) > max_answers:  # same draw count, another subset
+            n_capped += 1
+            assert len(sq.answers) == len(ans) == max_answers
+            assert set(sq.answers.tolist()) <= full
+        else:
+            assert np.array_equal(sq.answers, np.array(sorted(full), dtype=np.int64))
+    assert n_capped > 0
+    assert {k: new.stats[k] for k in ("sampled", "rejected")} == old.stats
+    assert old.stats["rejected"] > 0
+    # Every weighted witness draw: one per grounding attempt, plus one per
+    # node left unconstrained (negated, union and pi-type branches).
+    assert new.stats["table_draws"] == old.weighted_draws
+    if degree_weighted:
+        assert old.weighted_draws > old.stats["sampled"]
+    else:
+        assert old.weighted_draws == 0
