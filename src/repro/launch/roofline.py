@@ -58,6 +58,7 @@ _COLL_RE = re.compile(
 _SHAPE_RE = re.compile(r"([a-z0-9_]+)\[([0-9,]*)\]")
 _GROUPS_BRACE_RE = re.compile(r"replica_groups=\{\{([0-9,]+)\}")
 _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
+_CHANNEL_RE = re.compile(r"channel_id=(\d+)")
 
 
 def _type_bytes(type_str: str) -> int:
@@ -71,6 +72,13 @@ def _type_bytes(type_str: str) -> int:
                 n *= int(d)
         total += n * _DTYPE_BYTES[dt]
     return total
+
+
+def _largest_array_bytes(type_str: str) -> int:
+    """Bytes of the largest array in a (possibly tuple) type: a combined
+    collective carries many arrays at once."""
+    return max((_type_bytes(f"{dt}[{dims}]")
+                for dt, dims in _SHAPE_RE.findall(type_str)), default=0)
 
 
 def _group_size(line: str, default: int) -> int:
@@ -106,29 +114,41 @@ class CollectiveStats:
     payload_bytes: float
     by_type: Dict[str, float]
     counts: Dict[str, int]
+    largest_bytes: float            # largest array a collective returns
 
     def as_dict(self) -> Dict:
         return dataclasses.asdict(self)
 
 
 def parse_collectives(hlo_text: str, total_devices: int) -> CollectiveStats:
+    """Collectives of one partitioned module, each counted once: the TPU
+    compiler repeats an asynchronous collective inside every fusion that
+    continues it, all under the collective's one ``channel_id``."""
     by_type: Dict[str, float] = {}
     counts: Dict[str, int] = {}
     wire = 0.0
     payload = 0.0
+    largest = 0.0
+    seen = set()
     for line in hlo_text.splitlines():
         m = _COLL_RE.search(line)
         if not m:
             continue
+        ch = _CHANNEL_RE.search(line)
+        if ch:
+            if ch.group(1) in seen:
+                continue
+            seen.add(ch.group(1))
         type_str, op = m.group(1), m.group(2)
         size = _type_bytes(type_str)
         g = _group_size(line, total_devices)
         w = _wire_bytes(op, size, g)
         wire += w
         payload += size
+        largest = max(largest, float(_largest_array_bytes(type_str)))
         by_type[op] = by_type.get(op, 0.0) + w
         counts[op] = counts.get(op, 0) + 1
-    return CollectiveStats(wire, payload, by_type, counts)
+    return CollectiveStats(wire, payload, by_type, counts, largest)
 
 
 def roofline_terms(flops: float, bytes_accessed: float, wire_bytes: float,

@@ -204,6 +204,11 @@ class NGDBTrainer:
             for name in ("pipeline_wait", "sem_apply", "compile", "dispatch",
                          "retire")}
         self._inflight_gauge = self._obs.gauge("inflight")
+        # Bytes one device holds of the entity table: the whole table on one
+        # device, 1/N of it under the fsdp profile.
+        self._obs.gauge("entity_bytes_per_device").set(max(
+            s.data.nbytes for s in self.params["entity"].addressable_shards))
+        self._step_collectives: Dict[Tuple, object] = {}
         self.metrics_sink = MetricsSink(cfg.metrics_path)
 
     # ------------------------------------------------------------------ fns
@@ -270,8 +275,32 @@ class NGDBTrainer:
             )
         fn = jax.jit(step_fn, donate_argnums=self.ctx.donate_argnums(0, 1),
                      **jit_kwargs)
+        if jit_kwargs:
+            # Compile ahead of the first dispatch to read the partitioned
+            # module's collectives; the dispatch reuses this executable.
+            from repro.launch.roofline import parse_collectives
+
+            compiled = fn.lower(self.params, self.opt_state, *example).compile()
+            self._step_collectives[sig] = parse_collectives(
+                compiled.as_text(), self.ctx.n_devices)
         self._train_fns.put(sig, fn)
         return fn
+
+    def _collective_args(self, sig) -> Dict:
+        """Span args of a dispatch of ``sig``: its step's collectives (wire
+        bytes per device, payload bytes); none single-device."""
+        st = self._step_collectives.get(sig)
+        if st is None:
+            return {}
+        return {"collective_wire_bytes": st.wire_bytes,
+                "collective_payload_bytes": st.payload_bytes}
+
+    @property
+    def step_collectives(self) -> Dict[Tuple, object]:
+        """Collectives of each compiled train-step signature under a mesh
+        (``launch.roofline.CollectiveStats``), read once from the compiled
+        module; empty single-device."""
+        return self._step_collectives
 
     def compile_cache_stats(self) -> Dict[str, Dict[str, float]]:
         """Counters for every signature-keyed cache in the engine."""
@@ -307,15 +336,18 @@ class NGDBTrainer:
             # jit trace+compile — label the span accordingly.
             key = ("compile" if prepared.signature not in self._train_fns
                    else "dispatch")
-            fn = self._train_fn(prepared, example=(steps, ans, pos, neg))
             # pos/neg go in as host numpy: the jit places them per its
             # in_shardings (one transfer straight into the compiled layout);
             # a jnp.asarray here would commit to device 0 first and force a
             # second reshard transfer at dispatch under a mesh ctx.
-            with TRACER.timed(key, phases, self._phase_s[key]):
+            with TRACER.timed(key, phases, self._phase_s[key]) as ph:
+                fn = self._train_fn(prepared, example=(steps, ans, pos, neg))
                 self.params, self.opt_state, loss, per_q = fn(
                     self.params, self.opt_state, steps, ans, pos, neg
                 )
+                if ph.span is not None:
+                    ph.span.args.update(
+                        self._collective_args(prepared.signature))
             patterns = prepared.patterns
         else:  # query-level baseline: one fragmented pass per pattern group
             loss, per_q, patterns = self._query_level_step(queries, pos, neg)
@@ -569,18 +601,19 @@ class NGDBTrainer:
                                       step=item.seq):
                         self.params = self.sem_cache.apply_to(self.params,
                                                               item.sem_stage)
-                key = ("compile"
-                       if item.prepared.signature not in self._train_fns
-                       else "dispatch")
-                fn = self._train_fn(item.prepared,
-                                    example=(item.steps, item.ans,
-                                             item.pos, item.neg))
+                sig = item.prepared.signature
+                key = "compile" if sig not in self._train_fns else "dispatch"
                 with TRACER.timed(key, item.phases, self._phase_s[key],
-                                  step=item.seq):
+                                  step=item.seq) as ph:
+                    fn = self._train_fn(item.prepared,
+                                        example=(item.steps, item.ans,
+                                                 item.pos, item.neg))
                     self.params, self.opt_state, loss, per_q = fn(
                         self.params, self.opt_state, item.steps, item.ans,
                         item.pos, item.neg,
                     )
+                    if ph.span is not None:
+                        ph.span.args.update(self._collective_args(sig))
                 if self.mat_cache is not None:
                     # Dispatch replaced the params handle; scheduler-thread
                     # probes pinned to the old version stop matching and any

@@ -49,6 +49,29 @@ def test_parse_collectives():
     assert st.by_type["collective-permute"] == 32.0
 
 
+def test_parse_collectives_counts_each_channel_once():
+    """The TPU compiler repeats an asynchronous all-gather inside each
+    fusion that continues it, under the one channel_id: one collective."""
+    ag = ("  %all-gather.{i} = f32[512,129,400]{{0,2,1}} all-gather("
+          "%param_0.{i}), channel_id=360, replica_groups=[1,4]<=[4], "
+          "dimensions={{0}}")
+    text = "\n".join([ag.format(i=i) for i in range(15)] + [
+        "  %all-reduce.1 = f32[8,128]{1,0} all-reduce(%x), channel_id=7, "
+        "replica_groups={{0,1,2,3}}, to_apply=%add"])
+    st = parse_collectives(text, total_devices=4)
+    assert st.counts == {"all-gather": 1, "all-reduce": 1}
+    big = 512 * 129 * 400 * 4
+    assert st.payload_bytes == big + 8 * 128 * 4
+    assert st.by_type["all-gather"] == pytest.approx(big * 3 / 4)
+    assert st.largest_bytes == big
+    # a combined all-reduce is many arrays: its largest is one of them
+    st = parse_collectives(
+        "  %ar = (f32[1600]{0}, f32[800,400]{1,0}) all-reduce(%a, %b), "
+        "channel_id=9, replica_groups={{0,1,2,3}}", total_devices=4)
+    assert st.largest_bytes == 800 * 400 * 4
+    assert st.payload_bytes == (1600 + 800 * 400) * 4
+
+
 def test_wire_bytes_factors():
     assert _wire_bytes("all-gather", 100, 1) == 0.0
     assert _wire_bytes("all-reduce", 100, 2) == pytest.approx(100.0)
