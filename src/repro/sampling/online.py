@@ -39,6 +39,12 @@ def _ground_plan(tpl) -> Tuple:
 _GROUND_PLANS = {name: _ground_plan(tpl) for name, tpl in TEMPLATES.items()}
 
 
+def _in_sorted(values: np.ndarray, ref: np.ndarray) -> np.ndarray:
+    """Which of ``values`` occur in ``ref`` (ascending, non-empty)."""
+    at = ref.searchsorted(values).clip(max=len(ref) - 1)
+    return ref[at] == values
+
+
 @dataclasses.dataclass
 class SampledQuery:
     query: QueryInstance
@@ -76,7 +82,10 @@ class OnlineSampler:
             self._answer_cdf = None
         self._answer_cand = cand
         # table_draws: weighted answer/witness draws served by the table.
-        self.stats = {"sampled": 0, "rejected": 0, "table_draws": 0}
+        # neg_rows_resampled: rows of to_training_arrays whose corruptions
+        # hit an answer; neg_redraws: the values drawn again for them.
+        self.stats = {"sampled": 0, "rejected": 0, "table_draws": 0,
+                      "neg_rows_resampled": 0, "neg_redraws": 0}
 
     # ------------------------------------------------------------- grounding
     def _draw_answer(self) -> int:
@@ -154,12 +163,33 @@ class OnlineSampler:
     # --------------------------------------------------------- train tensors
     def to_training_arrays(self, batch: List[SampledQuery], n_negatives: int):
         """(queries, positives [B], negatives [B,K]) — negatives are uniform
-        corruptions filtered against the (sampled) answer set."""
-        pos = np.array([b.answers[self.rng.integers(len(b.answers))] for b in batch])
-        neg = self.rng.integers(0, self.kg.n_entities, size=(len(batch), n_negatives))
-        for i, b in enumerate(batch):
-            bad = np.isin(neg[i], b.answers)
+        corruptions filtered against the (sampled) answer set.
+
+        The whole batch is checked at once. Row i's answers and its
+        corruptions are offset by ``i * E``; with each row's corruptions
+        sorted, the batch's form one ascending array, and one
+        ``searchsorted`` of the sorted answer keys into it finds every row
+        with a hit. Only those rows are redrawn, in row order, so the
+        generator is consumed exactly as a per-row check would consume it."""
+        n_ent = self.kg.n_entities
+        lens = np.array([len(b.answers) for b in batch], dtype=np.int64)
+        answers = np.concatenate([b.answers for b in batch])
+        starts = np.concatenate(([0], lens.cumsum()))
+        # integers(0, lens) draws what one integers(len) per query draws.
+        pos = answers[starts[:-1] + self.rng.integers(0, lens)]
+        neg = self.rng.integers(0, n_ent, size=(len(batch), n_negatives))
+        row_base = np.arange(len(batch), dtype=np.int64) * n_ent
+        keys = np.sort(np.repeat(row_base, lens) + answers)
+        corrupt = (np.sort(neg, axis=1) + row_base[:, None]).ravel()
+        rows = np.unique(keys[_in_sorted(keys, corrupt)] // n_ent) if neg.size else ()
+        for i in rows:
+            # Row i's keys are one contiguous run of the sorted keys.
+            own = keys[starts[i]:starts[i + 1]] - row_base[i]
+            bad = _in_sorted(neg[i], own)
+            self.stats["neg_rows_resampled"] += 1
             while bad.any():  # resample collisions (rare on sparse graphs)
-                neg[i, bad] = self.rng.integers(0, self.kg.n_entities, bad.sum())
-                bad = np.isin(neg[i], b.answers)
+                n_bad = int(bad.sum())
+                self.stats["neg_redraws"] += n_bad
+                neg[i, bad] = self.rng.integers(0, n_ent, n_bad)
+                bad = _in_sorted(neg[i], own)
         return [b.query for b in batch], pos, neg

@@ -3,7 +3,7 @@ import pytest
 
 from repro.core import PATTERN_NAMES, TEMPLATES, OpType, QueryInstance, answer_query
 from repro.data import generate_synthetic_kg
-from repro.sampling import AdaptiveDistribution, OnlineSampler
+from repro.sampling import AdaptiveDistribution, OnlineSampler, SampledQuery
 
 
 def test_all_patterns_sampleable(tiny_kg):
@@ -177,3 +177,69 @@ def test_query_stream_matches_set_oracle_sampler(hub_kg, seed, degree_weighted):
         assert old.weighted_draws > old.stats["sampled"]
     else:
         assert old.weighted_draws == 0
+
+
+def _isin_training_arrays(rng, n_entities, batch, n_negatives, counts):
+    """``OnlineSampler.to_training_arrays`` as it was before the batched
+    membership check: one ``np.isin`` per query. The reference stream for
+    the tests below; ``counts`` records what its resample loop did."""
+    pos = np.array([b.answers[rng.integers(len(b.answers))] for b in batch])
+    neg = rng.integers(0, n_entities, size=(len(batch), n_negatives))
+    for i, b in enumerate(batch):
+        bad = np.isin(neg[i], b.answers)
+        counts["neg_rows_resampled"] += bool(bad.any())
+        rounds = 0
+        while bad.any():  # resample collisions (rare on sparse graphs)
+            counts["neg_redraws"] += int(bad.sum())
+            rounds += 1
+            neg[i, bad] = rng.integers(0, n_entities, bad.sum())
+            bad = np.isin(neg[i], b.answers)
+        counts["max_rounds"] = max(counts["max_rounds"], rounds)
+    return [b.query for b in batch], pos, neg
+
+
+@pytest.fixture(scope="module")
+def dense_kg():
+    """Few entities and many edges: answer sets cover much of the graph."""
+    return generate_synthetic_kg(40, 3, 900, seed=5, hub_exponent=1.3)
+
+
+def _batch(case, s):
+    if case != "descending":
+        return s.sample_batch(48 if case == "dense" else 64)
+    # Hand-built: both end entities, given in descending order.
+    e = s.kg.n_entities
+    return [SampledQuery(b.query, np.array(ans, dtype=np.int64))
+            for b, ans in zip(s.sample_batch(6), (
+                [e - 1, 0], [e - 1], [0], [e - 1, e // 2, 3, 0],
+                list(range(e - 1, -1, -1))[: e // 2], [e - 1, 1, 0]))]
+
+
+@pytest.mark.parametrize("case", ["dense", "unsorted", "descending"])
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 11])
+def test_training_arrays_match_per_row_isin(dense_kg, hub_kg, case, seed):
+    kg = hub_kg if case == "unsorted" else dense_kg
+    s = OnlineSampler(kg, seed=seed, max_answers=24 if case == "unsorted" else 512)
+    batch = _batch(case, s)
+    if case == "unsorted":
+        assert any((np.diff(b.answers) < 0).any() for b in batch)
+    ref_rng = np.random.default_rng()
+    ref_rng.bit_generator.state = s.rng.bit_generator.state
+    counts = {"neg_rows_resampled": 0, "neg_redraws": 0, "max_rounds": 0}
+    before = dict(s.stats)
+    queries, pos, neg = s.to_training_arrays(batch, n_negatives=32)
+    want_q, want_pos, want_neg = _isin_training_arrays(
+        ref_rng, kg.n_entities, batch, 32, counts)
+    assert queries == want_q
+    assert pos.dtype == want_pos.dtype == np.int64 and np.array_equal(pos, want_pos)
+    assert neg.dtype == want_neg.dtype == np.int64 and np.array_equal(neg, want_neg)
+    # The generator was consumed exactly as the per-row loop consumed it.
+    assert s.rng.bit_generator.state == ref_rng.bit_generator.state
+    for k in ("neg_rows_resampled", "neg_redraws"):
+        assert s.stats[k] - before[k] == counts[k]
+    assert counts["neg_rows_resampled"] > 0
+    if case == "dense":
+        assert counts["max_rounds"] >= 2
+    for i, b in enumerate(batch):
+        assert pos[i] in b.answers
+        assert not np.isin(neg[i], b.answers).any()
