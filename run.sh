@@ -5,7 +5,6 @@
 # common case:
 #
 #   ./run.sh -m repro.launch.train --dataset FB15k --model gqe ...
-#   ./run.sh benchmarks/run.py --only autotune
 #
 # Everything here is additive: variables you already exported win.
 set -euo pipefail

@@ -4,9 +4,9 @@ The pooled executor compiles one XLA program per ``ExecutionSchedule``
 signature (DESIGN.md §Pipeline). Because pool sizes are bucketed to powers of
 two and batches are canonicalized by pattern, the signature set is small and
 stable — after warmup every lookup should hit. The counters make that claim
-measurable: ``benchmarks/throughput.py --compare`` asserts a 100% hit rate
-(zero retraces) in steady state, and the training loop can surface
-``stats()`` for monitoring.
+measurable: ``tests/test_plan_cache.py::test_trainer_zero_steady_state_retraces``
+asserts zero retraces on a replay, sync and pipelined, and the training loop
+can surface ``stats()`` for monitoring.
 
 LRU eviction bounds host memory when a long-running job sees an unbounded
 stream of signatures (e.g. curriculum over pattern mixes): evicting a program

@@ -31,9 +31,8 @@ this engine compare bitwise, both using the same order).
 
 ``PlanCache`` makes the whole pipeline above CROSS-BATCH: a repeated batch
 (exact query-key tuple) skips steps 1-3 entirely, and a permutation of a
-seen batch skips 2-3 — which is what finally takes the per-batch host
-compile cost off the steady-state hot path (the CSE throughput regression
-BENCH_plan.json used to record).
+seen batch skips 2-3 — which is what takes the per-batch host compile
+cost off the steady-state hot path.
 """
 from __future__ import annotations
 

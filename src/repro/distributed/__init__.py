@@ -3,7 +3,6 @@ from repro.distributed.context import (
     make_execution_context,
     parse_mesh_spec,
 )
-from repro.distributed.pipeline_parallel import bubble_fraction, gpipe_forward
 from repro.distributed.sharding import (
     batch_shardings,
     cache_shardings,
@@ -21,6 +20,4 @@ __all__ = [
     "batch_shardings",
     "cache_shardings",
     "dp_axes",
-    "gpipe_forward",
-    "bubble_fraction",
 ]

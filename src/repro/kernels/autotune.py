@@ -18,8 +18,7 @@ winner so the tuning cost is paid once per machine:
 * **Timed sweep** — median-of-iters wall time through the PUBLIC ``ops``
   wrappers (what actually runs), default config always among the
   candidates, so the tuned config is never slower than the default on the
-  machine that tuned it (modulo timer noise; ``benchmarks/autotune.py``
-  gates this with paired trials).
+  machine that tuned it (modulo timer noise).
 * **Persisted cache** — crash-safe JSON (tmp + fsync + ``os.replace``, the
   ``SemanticStore`` idiom). A corrupt/partial/foreign-version file is
   REJECTED and retuned, never crashed on. ``REPRO_AUTOTUNE_CACHE`` names

@@ -158,8 +158,8 @@ def build_plan(host_devices: int = 0, threads: Optional[int] = None,
 
 
 def current_report() -> Dict[str, object]:
-    """What the CURRENT process actually launched with — recorded by the
-    autotune bench so a BENCH json says which knobs were live."""
+    """What the CURRENT process actually launched with — printed by
+    ``--report`` beside the plan, so it says which knobs are live."""
     return {
         "tcmalloc_active": tcmalloc_active(),
         "tcmalloc_found": find_tcmalloc(),

@@ -16,8 +16,8 @@ Composes with the rest of the launch surface:
   tables materialize into their NamedShardings and the scorer jit pins its
   logits replicated for host readback.
 
-``serve_batch`` remains the one-shot OFFLINE baseline (used by benchmarks
-and tests as the bit-identity oracle): it shares the engine's compiled
+``serve_batch`` remains the one-shot OFFLINE baseline (used by tests as
+the bit-identity oracle): it shares the engine's compiled
 encode programs and process-wide cached scorer, so the two paths produce
 identical results on identical micro-batch compositions — and repeated
 calls trace ``score_all`` exactly once (the historical per-call re-jit is

@@ -38,7 +38,7 @@ class PTEConfig:
 
 class StubPTE:
     """Frozen stub encoder with a real (small) transformer forward pass, so
-    joint-training benchmarks pay a genuine per-batch inference cost."""
+    encoding pays a genuine per-batch inference cost."""
 
     def __init__(self, cfg: PTEConfig = PTEConfig()):
         self.cfg = cfg

@@ -433,7 +433,7 @@ def precompute_semantic_table_to_store(
 # --------------------------------------------------------------------------
 
 class _ArrayReader:
-    """Adapter so tests/benchmarks can back a cache by an in-memory table."""
+    """Adapter so tests can back a cache by an in-memory table."""
 
     def __init__(self, table: np.ndarray):
         self._t = np.asarray(table, dtype=np.float32)

@@ -48,7 +48,7 @@ Offline/online parity: the engine and the one-shot ``launch/serve.py::
 serve_batch`` baseline share the SAME compiled encode programs, the SAME
 cached scorer and the SAME ``topk_desc`` — so on identical micro-batch
 compositions their per-request top-k is bit-identical, which
-``benchmarks/serving.py`` asserts under load.
+``tests/test_serving.py`` asserts on closed- and open-loop replays.
 """
 from __future__ import annotations
 

@@ -21,7 +21,8 @@ Division of labor per write:
 
 The fine-tune is a pure function of (params, triples, seed), so a
 synchronous rerun from the same inputs reproduces the background thread's
-output bitwise — the determinism gate ``benchmarks/live.py`` holds.
+output bitwise — ``tests/test_live.py::
+test_background_finetune_matches_sync_rerun`` holds it.
 """
 from __future__ import annotations
 

@@ -1,7 +1,6 @@
 """Closed- and open-loop load generation for the serving engine.
 
-Shared by ``launch/serve.py`` (the CLI driver) and ``benchmarks/serving.py``
-(the invariant-asserting load test):
+Shared by ``launch/serve.py`` (the CLI driver) and the serving tests:
 
 * **closed loop** — a fixed number of in-flight requests (``concurrency``);
   a new request is submitted only when one completes. Measures the maximum

@@ -87,7 +87,8 @@ def incremental_finetune(model, params, triples, *, steps: int = 4,
     serving engine's LIVE weights, concurrently read by the batcher thread,
     so they must survive this call unchanged. The background maintenance
     thread and a synchronous oracle rerun therefore produce bitwise-
-    identical params, which ``benchmarks/live.py`` gates."""
+    identical params, which ``tests/test_live.py::
+    test_background_finetune_matches_sync_rerun`` holds."""
     triples = np.asarray(triples, dtype=np.int64).reshape(-1, 3)
     if len(triples) == 0:
         return params, []
@@ -449,9 +450,9 @@ class NGDBTrainer:
               batches=None) -> List[Dict]:
         """Run ``n_steps``. ``batches`` pins the workload — a fixed batch
         list (cycled) or a zero-arg callable yielding batches (e.g. a seeded
-        sampler stream) — so benchmarks/tests can feed sync and pipelined
-        modes the SAME batches; otherwise batches come from the online
-        sampler."""
+        sampler stream) — so tests and the benchmark can feed sync and
+        pipelined modes the SAME batches; otherwise batches come from the
+        online sampler."""
         if self.cfg.pipeline and isinstance(self.executor, PooledExecutor):
             return self._train_pipelined(n_steps, log_every, batches=batches)
 

@@ -273,6 +273,33 @@ def test_executor_auto_snapshots_process_tuner(tiny_kg, tuner):
     assert ex2.tile_policy is None
 
 
+@pytest.mark.parametrize("pipeline", [False, True])
+def test_trainer_replay_compiles_nothing_under_tile_policy(tiny_kg, tuner,
+                                                           pipeline):
+    """With the process tuner set, the trainer's executor snapshots a live
+    tile policy, and replaying a warm batch list compiles nothing."""
+    from repro.models import ModelConfig, make_model
+    from repro.sampling import OnlineSampler
+    from repro.training import AdamConfig, NGDBTrainer, TrainConfig
+
+    model = make_model("gqe", ModelConfig(dim=8, gamma=6.0))
+    _tuned_policy_for(model, tuner)
+    at.set_tuner(tuner)
+    cfg = TrainConfig(batch_size=16, n_negatives=4, b_max=64, seed=0,
+                      adam=AdamConfig(lr=1e-3), pipeline=pipeline)
+    tr = NGDBTrainer(model, tiny_kg, cfg)
+    assert tr.executor.tile_policy
+    batches = [OnlineSampler(tiny_kg, seed=29).sample_batch(16)
+               for _ in range(2)]
+    tr.train(2, log_every=0, batches=batches)
+    tr._train_fns.reset_counters()
+    tr.executor.reset_cache_counters()
+    tr.train(4, log_every=0, batches=batches)
+    cs = tr.compile_cache_stats()
+    assert all(int(cs[k]["misses"]) == 0 for k in
+               ("train_step", "schedule", "encode", "encode_jit")), cs
+
+
 # ------------------------------------------------- ValueError contracts
 def test_scoring_shape_errors():
     from repro.kernels.scoring import scoring_pallas

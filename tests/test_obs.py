@@ -16,7 +16,8 @@ from repro.obs import (Counter, Gauge, Histogram, MetricsSink, TRACER,
                        get_registry, read_jsonl, validate_trace)
 from repro.obs.registry import MetricsRegistry, metric_key
 from repro.obs.report import cache_tables, summarize_metrics, summarize_trace
-from repro.serving import ServingConfig, ServingEngine, make_workload
+from repro.serving import (ServingConfig, ServingEngine, make_workload,
+                           run_closed_loop)
 from repro.training import AdamConfig, NGDBTrainer, TrainConfig
 
 
@@ -511,6 +512,43 @@ def test_worker_fed_scheduler_waits_in_sample_wait(tiny_kg):
     assert "sample" not in names["pipeline scheduler"]
     assert any("sample" in v for k, v in names.items()
                if k.startswith("sampling worker"))
+
+
+def test_tracing_keeps_losses_and_names_every_train_lane(tiny_kg):
+    """Tracing on leaves a pipelined run's losses bitwise unchanged; a
+    trace of a replayed run and an online-sampled one together has the
+    main, scheduler and sampling-worker lanes and every phase's span."""
+    from repro.sampling import OnlineSampler
+
+    batches = [OnlineSampler(tiny_kg, seed=29).sample_batch(8)]
+    off = _trainer(tiny_kg, pipeline=True)
+    off.train(3, log_every=0, batches=batches)
+    TRACER.enable(jax_annotations=False)
+    on = _trainer(tiny_kg, pipeline=True)
+    on.train(3, log_every=0, batches=batches)
+    _trainer(tiny_kg, pipeline=True).train(2, log_every=0)
+    s = validate_trace(TRACER.to_json())
+    TRACER.disable()
+    assert [h["loss"] for h in on.history] == [h["loss"] for h in off.history]
+    assert {"main dispatch", "pipeline scheduler",
+            "sampling worker 0"} <= set(s["lanes"])
+    assert {"sample", "negatives", "schedule", "transfer", "pipeline_wait",
+            "compile", "dispatch", "retire"} <= set(s["names"])
+
+
+def test_multi_client_serving_trace_has_a_lane_per_client(tiny_kg):
+    engine = _engine(tiny_kg, cfg=ServingConfig(max_batch=8))
+    try:
+        TRACER.enable(jax_annotations=False)
+        run_closed_loop(engine, make_workload(tiny_kg, 24, seed=7),
+                        concurrency=8, threads=3)
+        s = validate_trace(TRACER.to_json())
+        TRACER.disable()
+    finally:
+        engine.close()
+    assert {"serving batcher", "client 0", "client 1",
+            "client 2"} <= set(s["lanes"])
+    assert {"request", "batch", "encode", "score", "select"} <= set(s["names"])
 
 
 def test_phase_counters_register_in_snapshot(tiny_kg, mixed_queries):

@@ -20,7 +20,8 @@ from repro.core import (MaterializedSubqueryCache, PooledExecutor)
 from repro.data.kg import generate_synthetic_kg
 from repro.models import ModelConfig, make_model, model_names
 from repro.sampling import OnlineSampler
-from repro.serving import (ServingConfig, ServingEngine, make_workload)
+from repro.serving import (ServingConfig, ServingEngine, make_workload,
+                           run_closed_loop)
 from repro.training import NGDBTrainer, TrainConfig
 
 
@@ -135,6 +136,26 @@ def test_engine_replay_reuses_plans_across_engine_instances(tiny_kg):
                          if k not in ("latency_ms", "batch_size")}
                         for r in rs]
     assert strip(r1) == strip(r2)
+
+
+def test_engine_duplicate_replay_serves_rows_from_materialized_cache(tiny_kg):
+    """Duplicate-heavy traffic through an engine with a materialized-row
+    cache: once warm, at least 90% of rows come from the cache and nothing
+    compiles."""
+    model, params = _model_params(tiny_kg)
+    mat = MaterializedSubqueryCache(128)
+    mat.watch_kg(tiny_kg)
+    uniq = make_workload(tiny_kg, 32, seed=7)
+    workload = [uniq[i % len(uniq)] for i in range(4 * len(uniq))]
+    with ServingEngine(model, params, executor=PooledExecutor(model, b_max=64),
+                       cfg=ServingConfig(max_batch=16),
+                       mat_cache=mat) as engine:
+        run_closed_loop(engine, workload, concurrency=16)
+        engine.reset_counters()
+        run_closed_loop(engine, workload, concurrency=16)
+        st = engine.stats()
+    assert st["mat_cache"]["hit_rate"] >= 0.9, st["mat_cache"]
+    assert st["retraces"] == 0, st["caches"]
 
 
 # ----------------------------------------------------- materialized staleness

@@ -181,3 +181,67 @@ def test_compile_empty_batch():
     assert plan.sched.steps == []
     assert len(plan.answer_slots) == 0
     assert plan.report.nodes_before == 0
+
+
+# ----------------------------------------- overlap-heavy sampled workload
+_PREFIX = {"3p": "2p", "2p": "1p"}  # chain pattern -> its prefix pattern
+
+
+def _sampled_overlap_batches(kg, n_batches, batch_size, seed=13):
+    """Half freshly sampled chain/branch queries, then the prefix of each
+    chain (a 1p that IS the first hop of a co-batched 2p, ...), then
+    repeats: the overlap profile of serving traffic, where popular
+    subqueries recur across concurrent requests."""
+    from repro.core.patterns import answer_query
+    from repro.sampling import OnlineSampler
+    from repro.sampling.online import SampledQuery
+
+    sampler = OnlineSampler(kg, patterns=("1p", "2p", "3p", "ip", "pi", "2i"),
+                            seed=seed)
+    batches = []
+    for _ in range(n_batches):
+        base = sampler.sample_batch(batch_size // 2)
+        batch = list(base)
+        for b in base:
+            pre = _PREFIX.get(b.query.pattern)
+            if pre is not None:
+                n_rel = 1 if pre == "1p" else 2
+                pq = QueryInstance(pre, b.query.anchors[:1].copy(),
+                                   b.query.relations[:n_rel].copy())
+                batch.append(SampledQuery(
+                    pq, np.fromiter(answer_query(kg, pq), np.int64)))
+        batch += [base[i % len(base)] for i in range(batch_size - len(batch))]
+        batches.append(batch[:batch_size])
+    return batches
+
+
+def test_cse_saves_a_quarter_of_pooled_rows_on_overlap_traffic(tiny_kg):
+    """Cross-query CSE merges at least 25% of the pooled rows of the
+    overlap-heavy workload."""
+    before = after = 0
+    for batch in _sampled_overlap_batches(tiny_kg, 4, 32):
+        plan = compile_batch([s.query for s in batch], model_name="m")
+        before += plan.report.nodes_before
+        after += plan.report.nodes_after
+    assert (before - after) / before >= 0.25, (before, after)
+
+
+@pytest.mark.parametrize("name", model_names())
+def test_cse_training_losses_match_no_cse(tiny_kg, name):
+    """Training on the overlap workload with CSE on vs off: the first step
+    runs from identical params, so its loss is bitwise equal; later steps
+    may differ by the reassociated sum of a shared node's cotangents, so
+    the whole sequence stays within 1e-5."""
+    from repro.training import AdamConfig, NGDBTrainer, TrainConfig
+
+    batches = _sampled_overlap_batches(tiny_kg, 1, 16)
+    losses = {}
+    for cse in (True, False):
+        cfg = TrainConfig(batch_size=16, n_negatives=4, b_max=32, seed=0,
+                          adam=AdamConfig(lr=1e-3), cse=cse)
+        tr = NGDBTrainer(make_model(name, ModelConfig(dim=8, gamma=6.0)),
+                         tiny_kg, cfg)
+        tr.train(2, log_every=0, batches=batches)
+        losses[cse] = [h["loss"] for h in tr.history]
+    assert losses[True][0] == losses[False][0], losses
+    np.testing.assert_allclose(losses[True], losses[False], rtol=0, atol=1e-5)

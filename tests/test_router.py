@@ -354,3 +354,85 @@ def test_tenant_and_replica_metric_labels(served):
     # ...and the historical unlabeled keys still do (single-engine path),
     # with the labeled tier traffic NOT aliasing into them.
     assert snap.get("serving_submitted") == 0
+
+
+_DEEP_PATTERNS = ["3p", "3i", "ip", "pi", "inp", "pin", "pni", "up", "3in"]
+
+
+def _lockstep_replay(router, blocks):
+    """One block in flight at a time: each block is one micro-batch on its
+    home replica, so every replay forms the same compositions."""
+    for blk in blocks:
+        for f in router.submit_many(blk):
+            f.result(timeout=120)
+
+
+def test_affinity_replay_serves_each_replica_from_its_cache(served):
+    """Deep queries partitioned by rendezvous over 4 replicas, each
+    replica's share within its materialized-row budget and the union
+    beyond it: after a warm pass, a replay through the pool compiles
+    nothing and serves every row from the home replica's cache, while one
+    replica with the same budget serves none from its cache."""
+    kg, model, params = served
+    batch, rids = 8, [0, 1, 2, 3]
+    uniq = {q.key(): q for q in make_workload(kg, 96, seed=13,
+                                              patterns=_DEEP_PATTERNS)}
+    streams = {rid: [] for rid in rids}
+    for q in uniq.values():
+        streams[rendezvous_rank(query_topology_key(q), rids)[0]].append(q)
+    shares = [qs[:len(qs) // batch * batch] for qs in streams.values()]
+    blocks = [qs[i:i + batch] for qs in shares
+              for i in range(0, len(qs), batch)]
+    budget = max(map(len, shares)) + batch
+    assert sum(map(len, blocks)) >= budget + 2 * batch  # union overflows
+    cfg = ServingConfig(max_batch=batch, max_wait_ms=2000.0)
+    hit_rates = {}
+    for n in (len(rids), 1):
+        pool = ReplicaPool(model, params, n_replicas=n, cfg=cfg,
+                           mat_budget_rows=budget)
+        with Router(pool, cfg=RouterConfig(spill_width=0)) as router:
+            _lockstep_replay(router, blocks)
+            pool.reset_counters()
+            _lockstep_replay(router, blocks)
+            stats = {rid: r.stats() for rid, r in pool.replicas().items()}
+        assert all(st["retraces"] == 0 for st in stats.values()), stats
+        hit_rates[n] = [st["mat_cache"]["hit_rate"] for st in stats.values()
+                        if st["submitted"]]
+    assert hit_rates[len(rids)] == [1.0] * len(rids)
+    assert hit_rates[1] == [0.0]
+
+
+def test_overload_sheds_only_low_priority_on_a_real_pool(served):
+    """High-priority work queued on stopped replicas: a low-priority flood
+    is shed at once with typed, counted ``ShedError``s that are not
+    failures; once the replicas start, every admitted request of both
+    tenants is served."""
+    kg, model, params = served
+    qs = make_workload(kg, 24, seed=21)
+    pool = ReplicaPool(model, params, n_replicas=2, started=False,
+                       cfg=ServingConfig(max_batch=8, max_wait_ms=2.0))
+    router = Router(pool, tenants=[TenantSpec("gold", "high"),
+                                   TenantSpec("bronze", "low")],
+                    cfg=RouterConfig(spill_width=0, low_priority_depth=1))
+    with router:
+        gold = [router.submit(q, tenant="gold") for q in qs[:8]]
+        bronze, sheds = [], 0
+        for q in qs[8:]:
+            t0 = time.perf_counter()
+            try:
+                bronze.append(router.submit(q, tenant="bronze"))
+            except ShedError as e:
+                assert e.reason == "backpressure"
+                sheds += 1
+            assert time.perf_counter() - t0 < 0.1  # never blocks
+        for rep in pool.replicas().values():
+            rep.start()
+        for f in gold + bronze:
+            assert f.result(timeout=120)["top_entities"]
+        st = router.stats()["tenants"]
+    assert sheds > 0
+    assert st["bronze"]["shed"]["backpressure"] == sheds
+    assert (st["bronze"]["completed"], st["bronze"]["failures"]) == (
+        len(bronze), 0)
+    assert st["gold"]["completed"] == 8 and st["gold"]["failures"] == 0
+    assert sum(st["gold"]["shed"].values()) == 0

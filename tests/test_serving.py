@@ -187,6 +187,26 @@ def test_engine_zero_steady_state_retraces_on_replay(tiny_kg):
     assert lat["p50"] <= lat["p95"] <= lat["p99"] <= lat["max"]
     assert all(r["latency_ms"] > 0 for r in rep.results)
     assert all(r["batch_size"] == 8 for r in rep.results)
+    assert rep.offered_qps > 0  # the arrival rate it measured
+
+
+def test_engine_closed_loop_replay_compiles_nothing(tiny_kg):
+    """A closed-loop replay of the warm-up workload, then an open-loop
+    burst of it, both compile nothing, and every computed row matches the
+    offline ``serve_batch`` on the logged compositions."""
+    model, params, ex = _setup(tiny_kg)
+    cfg = ServingConfig(max_batch=8, max_wait_ms=1000.0, record_batches=True)
+    workload = make_workload(tiny_kg, 32, seed=11)
+    with ServingEngine(model, params, executor=ex, cfg=cfg) as engine:
+        run_closed_loop(engine, workload, concurrency=8)
+        engine.reset_counters(clear_log=True)
+        run_closed_loop(engine, workload, concurrency=8)
+        run_open_loop(engine, workload)
+        assert engine.retraces() == 0, engine.stats()["caches"]
+        log = list(engine.batch_log)
+    ex2 = PooledExecutor(model, b_max=64)
+    assert check_against_offline(
+        log, lambda qs: serve_batch(model, params, ex2, qs)[0]) == 64
 
 
 def test_engine_out_of_core_semantic_serving(tiny_kg, mixed_queries, rng):
