@@ -4,7 +4,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.scipy.special import betaln, digamma
+from jax.lax import lgamma
+from jax.scipy.special import digamma
 
 from repro.models.base import ModelConfig, QueryEncoder, mlp_apply, mlp_params, register_model
 
@@ -14,6 +15,74 @@ _MAXP = 40.0
 
 def _clip(p):
     return jnp.clip(p, _EPS, _MAXP)
+
+
+def _betaln(x, y):
+    return lgamma(x) + lgamma(y) - lgamma(x + y)
+
+
+def _trigamma(x):
+    """psi'(x) for x > 0: six steps of psi'(x) = psi'(x + 1) + 1/x^2, then
+    the asymptotic series. Arithmetic only: no polygamma, no zeta."""
+    acc = jnp.zeros_like(x)
+    for _ in range(6):
+        acc = acc + 1.0 / (x * x)
+        x = x + 1.0
+    r = 1.0 / x
+    r2 = r * r
+    series = r + r2 * (0.5 + r * (1.0 / 6 + r2 * (-1.0 / 30 + r2 * (
+        1.0 / 42 - r2 * (1.0 / 30)))))
+    return acc + series
+
+
+def _sum_to(g, shape):
+    """Sum ``g`` back to ``shape``, over the axes it was broadcast along."""
+    lead = g.ndim - len(shape)
+    axes = tuple(range(lead)) + tuple(lead + i for i, n in enumerate(shape) if n == 1)
+    return jnp.sum(g, axis=axes).reshape(shape)
+
+
+def _kl_terms(aq, bq, ae, be):
+    pa, pb, pab = digamma(ae), digamma(be), digamma(ae + be)
+    kl = (
+        _betaln(aq, bq)
+        - _betaln(ae, be)
+        + (ae - aq) * pa
+        + (be - bq) * pb
+        + (aq - ae + bq - be) * pab
+    )
+    return kl, (pa, pb, pab)
+
+
+@jax.custom_vjp
+def _beta_kl(aq, bq, ae, be):
+    """Elementwise KL( Beta(ae,be) || Beta(aq,bq) ), broadcasting. Its
+    gradient is written in closed form, so autodiff never differentiates
+    digamma (whose derivative XLA expands as a Hurwitz zeta)."""
+    return _kl_terms(aq, bq, ae, be)[0]
+
+
+def _beta_kl_fwd(aq, bq, ae, be):
+    kl, psi = _kl_terms(aq, bq, ae, be)
+    # The barrier keeps the residuals as computed here: without it XLA
+    # recomputes two of the three digammas inside the backward's fusion.
+    return kl, jax.lax.optimization_barrier((aq, bq, ae, be, *psi))
+
+
+def _beta_kl_bwd(res, g):
+    aq, bq, ae, be, pa, pb, pab = res
+    s = aq - ae + bq - be
+    t = s * _trigamma(ae + be)
+    q_ab = digamma(aq + bq)
+    return (
+        _sum_to(g * (digamma(aq) - q_ab - pa + pab), aq.shape),
+        _sum_to(g * (digamma(bq) - q_ab - pb + pab), bq.shape),
+        _sum_to(g * ((ae - aq) * _trigamma(ae) + t), ae.shape),
+        _sum_to(g * ((be - bq) * _trigamma(be) + t), be.shape),
+    )
+
+
+_beta_kl.defvjp(_beta_kl_fwd, _beta_kl_bwd)
 
 
 @register_model("betae")
@@ -73,11 +142,5 @@ class BetaE(QueryEncoder):
         aq, bq = self._split(q)
         aq, bq = _clip(aq), _clip(bq)
         # KL( Beta(ae,be) || Beta(aq,bq) ), summed over dims.
-        kl = (
-            betaln(aq, bq)
-            - betaln(ae, be)
-            + (ae - aq) * digamma(ae)
-            + (be - bq) * digamma(be)
-            + (aq - ae + bq - be) * digamma(ae + be)
-        )
+        kl = _beta_kl(aq, bq, ae, be)
         return jnp.sum(kl, axis=-1) / jnp.sqrt(self.cfg.dim)
